@@ -35,6 +35,8 @@ def main() -> None:
                          "adaptive_k,theory,roofline,kernel,client,arrival,"
                          "arch,adversarial")
     args = ap.parse_args()
+    from repro.utils.xla import enable_compile_cache
+    enable_compile_cache()
 
     max_time = 20.0 if args.quick else (90.0 if args.full else 45.0)
     tasks = (("synthetic-1-1", "femnist", "shakespeare") if args.full

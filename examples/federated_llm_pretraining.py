@@ -32,7 +32,7 @@ ap.add_argument("--memory-budget-mb", type=float, default=0.0,
                      "the chosen plan is reported below")
 ap.add_argument("--pallas-agg", action="store_true",
                 help="route aggregation through the fused fedagg kernel "
-                     "(interpret mode on CPU)")
+                     "(compiled on TPU, interpreted on CPU)")
 args = ap.parse_args()
 
 out = run_arch_federated(args.arch, steps=args.steps,

@@ -16,6 +16,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import FedConfig
 from repro.core import compression, tasks
@@ -92,9 +93,10 @@ class Client:
 
     # --- cohort-engine hooks (repro.core.cohort stacks many clients) ---
     def stage_cohort(self, params: PyTree):
-        """Per-client state the cohort engine stacks: (momentum, lr)."""
+        """Per-client state the cohort engine stacks on the host:
+        (momentum, lr)."""
         if self._mu is None:
-            self._mu = pt.tree_zeros_like(params)
+            self._mu = pt.tree_zeros_host(params)
         return self._mu, self._lr()
 
     def commit_cohort(self, mu: PyTree) -> None:
@@ -152,7 +154,7 @@ class Client:
         if self._flatspec is None:
             self._flatspec = spec
         if self._residual is None:
-            return spec.zeros()
+            return np.zeros((spec.n_padded,), np.float32)
         return self._residual
 
     def commit_residual(self, residual) -> None:

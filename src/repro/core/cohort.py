@@ -76,7 +76,6 @@ from typing import Any, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import CLIENT_ENGINES
 from repro.core import compression
@@ -206,8 +205,8 @@ def _sharded_core(task, n_pods: int, masked: bool,
             return _dense_body(task, params, mu, xs, ys, lrs,
                                beta, prox_mu)
         n_in = 5
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,) * n_in,
-                   out_specs=(spec, spec, spec))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * n_in,
+                       out_specs=(spec, spec, spec))
     return jax.jit(fn)
 
 
@@ -289,8 +288,8 @@ def _wire_core(n_pods: int, mode: str):
         return q, vec - q.astype(jnp.float32)
 
     n_out = 3 if mode == "int8" else 2
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                   out_specs=(spec,) * n_out)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=(spec,) * n_out)
     return jax.jit(fn)
 
 
@@ -347,7 +346,7 @@ def _run_chunk(task, fed, engine: str, p_src, mus, lrs_list, x_rows,
         xs_rows.append(bx)
         ys_rows.append(by)
         lrs[i] = lrs_list[i]
-    zeros_mu = pt.tree_zeros_like(template)
+    zeros_mu = pt.tree_zeros_host(template)
     mus = list(mus)
     for _ in range(c_pad - c_real):    # padded client rows: discarded
         xs_rows.append(xs_rows[0])

@@ -190,13 +190,17 @@ class AsyncFedEDServer(AsyncServer):
       vectors, and every update runs through the fused fedagg kernels — a
       norms sweep and an AXPY sweep (DESIGN.md §4). Bursts drained via
       :meth:`on_update_batch` go through the multi-delta batched kernel.
+      The kernels run compiled on TPU and interpreted on CPU
+      (``fedagg.resolve_interpret``); ``interpret`` overrides that only
+      when given.
     """
 
     name = "asyncfeded"
 
     def __init__(self, params: PyTree, fed: FedConfig,
                  gmis_mode: str = "ring", per_leaf: bool = False,
-                 backend: str = "pytree", interpret: bool = True):
+                 backend: str = "pytree",
+                 interpret: Optional[bool] = None):
         if backend not in ("pytree", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
         if backend == "pallas" and per_leaf:
@@ -256,14 +260,23 @@ class AsyncFedEDServer(AsyncServer):
             # absorbed by the (value-transparent) zero padding
             self._flat = pt.FlatParams.from_tree(
                 value, block=ops._BLOCK * self._shards)
-            self._zeros = self._flat.spec.zeros()
+            self._zeros = None
             if self._shards > 1:
                 self._flat = self._flat.replace(
                     self._sharded.place_flat(self._flat.vec, self._shards))
-                self._zeros = self._sharded.place_flat(self._zeros,
-                                                       self._shards)
         else:
             self._params = value
+
+    def _zeros_vec(self):
+        """The zero vector filling the x_stale slot of the displacement
+        kernels. Built on first use, so a ring-mode server never holds a
+        spare model-sized buffer on the device."""
+        if self._zeros is None:
+            self._zeros = self._flat.spec.zeros()
+            if self._shards > 1:
+                self._zeros = self._sharded.place_flat(self._zeros,
+                                                       self._shards)
+        return self._zeros
 
     def _gmis_state(self):
         """What the GMIS stores: flat vectors under the pallas backend (a
@@ -361,7 +374,7 @@ class AsyncFedEDServer(AsyncServer):
                     self._agg["flat_aggregate_displacement_q"](
                         self._flat.vec,
                         self.gmis.displacement(upd.client_id), q,
-                        qscales, self._zeros, lam=fed.lam, eps=fed.eps,
+                        qscales, self._zeros_vec(), lam=fed.lam, eps=fed.eps,
                         cap=fed.staleness_cap, interpret=self._interpret))
                 self.gmis.release(upd.client_id)
             else:
@@ -386,7 +399,7 @@ class AsyncFedEDServer(AsyncServer):
             new_vec, gamma, eta, dist, dnorm = (
                 self._agg["flat_aggregate_displacement"](
                     self._flat.vec, self.gmis.displacement(upd.client_id),
-                    d, self._zeros, lam=fed.lam, eps=fed.eps,
+                    d, self._zeros_vec(), lam=fed.lam, eps=fed.eps,
                     cap=fed.staleness_cap, interpret=self._interpret))
             self.gmis.release(upd.client_id)
         else:

@@ -9,29 +9,58 @@ delta again for the AXPY). These kernels do it in two single-pass phases:
            ||delta||^2  -> host combines to gamma, eta (Eq. 6/7, scalars).
   phase 2  fedagg_axpy  : one pass computing x_t + eta * delta (Eq. 5).
 
-Tiling: the flattened parameter vector is reshaped to (n_blocks, 8, 128) —
-the TPU float32 VMEM tile — with zero padding to a multiple of BLOCK.
-Padding contributes 0 to both sums and is sliced off after the AXPY.
+Tiling: the flattened parameter vector is reshaped to (rows, 128) and
+swept BLOCK_ROWS rows per grid step, with zero padding to a multiple of
+BLOCK. Padding contributes 0 to both sums and is sliced off after the AXPY.
+Every block the kernels read or write satisfies the Mosaic (TPU) rule: its
+last two dimensions are (8, 128)-aligned or span the whole array. Partial
+sums therefore leave each grid step as one lane-dense (8, 128) tile per
+quantity (or a whole-array-width (B, B) / (B, 1) block in the batched
+sweeps), and the wrapper finishes the reduction.
+
+Kernel mode follows the platform (:func:`resolve_interpret`): the Pallas
+interpreter on CPU, compiled Mosaic on TPU.
 """
 from __future__ import annotations
 
-import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # one grid step processes BLOCK_ROWS x 128 elements resident in VMEM
 LANES = 128
+SUBLANES = 8                           # f32 vreg / VMEM tile: (8, 128)
 BLOCK_ROWS = 512                       # 512*128*4B = 256 KiB per operand tile
 
 # compressed-delta transport (DESIGN.md §13): one f32 scale per QBLOCK
 # int8 elements. QBLOCK_ROWS divides every rows-per-step the row schedule
 # can pick (the halving ladder floors at 8), so a VMEM tile always holds a
 # whole number of scale blocks and dequantization stays one broadcast
-# multiply per tile.
+# multiply per scale block.
 QBLOCK_ROWS = 8
 QBLOCK = QBLOCK_ROWS * LANES           # 1024 elements per int8 scale
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The one place the kernel mode is decided. ``None`` (every default
+    on the main path) follows the process's default backend at call time:
+    the Pallas interpreter on CPU, where the tests run, and compiled
+    Mosaic on TPU. Any other backend raises — there is no silent fallback
+    to the interpreter. An explicit bool wins (a compile for a described
+    TPU from a CPU host passes ``False``)."""
+    if interpret is not None:
+        return bool(interpret)
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"fedagg kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default backend is {platform!r}")
 
 
 def _f32(x: jax.Array) -> jax.Array:
@@ -40,9 +69,34 @@ def _f32(x: jax.Array) -> jax.Array:
     return x if x.dtype == jnp.float32 else x.astype(jnp.float32)
 
 
-# operand budget per grid step of the multi-delta kernels (half of a
-# 16 MiB/core VMEM, leaving room for outputs and double buffering)
+def _fold(x: jax.Array) -> jax.Array:
+    """Sum a (rows, LANES) f32 tile down to one (SUBLANES, LANES) tile: a
+    tile-aligned sublane split plus elementwise vreg adds, so per-step
+    partial sums leave the kernel lane-dense."""
+    return x.reshape(-1, SUBLANES, LANES).sum(axis=0)
+
+
+def _partials(g: int):
+    """Out spec + shape of one per-step (SUBLANES, LANES) partial-sum tile
+    per grid step; the wrapper sums the whole array."""
+    return (pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
+            jax.ShapeDtypeStruct((g * SUBLANES, LANES), jnp.float32))
+
+
+# operand bytes per grid step of the multi-delta kernels (one buffer of
+# each resident input tile)
 _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+# scoped-VMEM ceiling granted to the multi-delta sweeps. Double buffering
+# plus the in-kernel f32 drift / dequant / flattened copies take the Gram
+# sweep at the knees past the compiler's default 16 MiB scope: compiled
+# for v5e it needs more than 32 and at most 48 MiB, so 64 leaves margin
+# inside the core's 128 MiB of VMEM.
+_BATCHED_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _batched_params(interpret: bool):
+    return (None if interpret else
+            pltpu.CompilerParams(vmem_limit_bytes=_BATCHED_VMEM_LIMIT_BYTES))
 
 
 def batched_b_max(delta_bytes: int = 4) -> int:
@@ -89,17 +143,15 @@ def _batched_rows(b: int, n: int, interpret: bool,
     return rows
 
 
-def _norms_kernel(xt_ref, xs_ref, d_ref, out_ref):
-    xt = xt_ref[...].astype(jnp.float32)
-    xs = xs_ref[...].astype(jnp.float32)
-    d = d_ref[...].astype(jnp.float32)
-    diff = xt - xs
-    out_ref[0, 0] = jnp.sum(diff * diff)
-    out_ref[0, 1] = jnp.sum(d * d)
+def _norms_kernel(xt_ref, xs_ref, d_ref, dist_ref, dn_ref):
+    diff = _f32(xt_ref[...]) - _f32(xs_ref[...])
+    d = _f32(d_ref[...])
+    dist_ref[...] = _fold(diff * diff)
+    dn_ref[...] = _fold(d * d)
 
 
 def fedagg_norms(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
-                 *, interpret: bool = True) -> jax.Array:
+                 *, interpret: Optional[bool] = None) -> jax.Array:
     """Inputs: flat (n,) arrays (zero-padded to BLOCK multiple by ops.py).
     Returns (2,) f32: [||x_t - x_stale||^2, ||delta||^2]."""
     n = x_t.shape[0]
@@ -107,15 +159,16 @@ def fedagg_norms(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
     assert n % block == 0, (n, block)
     g = n // block
     shaped = lambda a: a.reshape(g * BLOCK_ROWS, LANES)
-    partial = pl.pallas_call(
+    spec, shape = _partials(g)
+    dist, dn = pl.pallas_call(
         _norms_kernel,
         grid=(g,),
         in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))] * 3,
-        out_specs=pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, 2), jnp.float32),
-        interpret=interpret,
+        out_specs=[spec, spec],
+        out_shape=[shape, shape],
+        interpret=resolve_interpret(interpret),
     )(shaped(x_t), shaped(x_stale), shaped(delta))
-    return jnp.sum(partial, axis=0)
+    return jnp.stack([jnp.sum(dist), jnp.sum(dn)])
 
 
 def _axpy_kernel(eta_ref, xt_ref, d_ref, out_ref):
@@ -126,7 +179,7 @@ def _axpy_kernel(eta_ref, xt_ref, d_ref, out_ref):
 
 
 def fedagg_axpy(x_t: jax.Array, delta: jax.Array, eta: jax.Array,
-                *, interpret: bool = True) -> jax.Array:
+                *, interpret: Optional[bool] = None) -> jax.Array:
     """x_t + eta * delta, flat (n,) blocked through VMEM. eta: scalar."""
     n = x_t.shape[0]
     block = BLOCK_ROWS * LANES
@@ -143,49 +196,75 @@ def fedagg_axpy(x_t: jax.Array, delta: jax.Array, eta: jax.Array,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(delta))
     return out.reshape(n)
 
 
-def _norms_batched_kernel(xt_ref, xs_ref, d_ref, dist_ref, dn_ref,
-                          c_ref, g_ref):
-    """Multi-delta phase 1: one tile of x_t against B stacked (stale, delta)
-    pairs. Beyond the per-update norms, emits the cross terms needed to make
-    the batched apply *sequentially equivalent* (DESIGN.md §4.3):
+def _nt_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b.T`` contracting the lane axis, at full f32 precision: the
+    MXU's default for f32 operands is one bf16 pass (~2e-3 relative on
+    v5e), too coarse for the sequential-equivalence schedule."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
-        dist_ref[b] = ||x_t - x_stale_b||^2   (partial)
-        dn_ref[b]   = ||delta_b||^2           (partial)
+
+def _gram_partials(xt, xs, d, dist_ref, dn_ref, c_ref, g_ref):
+    """Multi-delta phase 1 on one f32 tile of x_t (rows, LANES) against B
+    stacked (stale, delta) tiles (B, rows, LANES). Beyond the per-update
+    norms, emits the cross terms needed to make the batched apply
+    *sequentially equivalent* (DESIGN.md §4.3):
+
+        dist_ref[b] = ||x_t - x_stale_b||^2   (partial, (B, 1))
+        dn_ref[b]   = ||delta_b||^2           (partial, (B, 1))
         c_ref[b,k]  = <x_t - x_stale_b, delta_k>
         g_ref[k,l]  = <delta_k, delta_l>
 
-    The two Gram blocks go through the MXU as (B, tile) @ (tile, B) matmuls.
+    The two Gram blocks go through the MXU as (B, tile) x (B, tile)
+    contractions.
     """
-    b = d_ref.shape[0]
-    xt = _f32(xt_ref[...])                          # (rows, LANES)
-    xs = _f32(xs_ref[...])                          # (B, rows, LANES)
-    d = _f32(d_ref[...]).reshape(b, -1)
+    b = d.shape[0]
+    d = d.reshape(b, -1)
     s = (xt[None] - xs).reshape(b, -1)              # drift vectors
-    # 2-D dots: MXU on TPU, one sgemm each on the CPU interpreter
-    c = jnp.dot(s, d.T, preferred_element_type=jnp.float32)
-    g = jnp.dot(d, d.T, preferred_element_type=jnp.float32)
-    dist_ref[0, :] = jnp.sum(s * s, axis=1)
-    dn_ref[0, :] = jnp.sum(d * d, axis=1)
-    c_ref[0] = c
-    g_ref[0] = g
+    c_ref[0] = _nt_dot(s, d)
+    g_ref[0] = _nt_dot(d, d)
+    dist_ref[0] = jnp.sum(s * s, axis=1, keepdims=True)
+    dn_ref[0] = jnp.sum(d * d, axis=1, keepdims=True)
+
+
+def _norms_batched_kernel(xt_ref, xs_ref, d_ref, *out_refs):
+    _gram_partials(_f32(xt_ref[...]), _f32(xs_ref[...]), _f32(d_ref[...]),
+                   *out_refs)
+
+
+def _gram_outputs(g: int, b: int):
+    """Out specs + shapes of the four per-step Gram partials; every block
+    spans its array's last two dims, (B, 1) or (B, B)."""
+    specs = [pl.BlockSpec((1, b, 1), lambda i: (i, 0, 0))] * 2 + [
+        pl.BlockSpec((1, b, b), lambda i: (i, 0, 0))] * 2
+    shapes = [jax.ShapeDtypeStruct((g, b, 1), jnp.float32)] * 2 + [
+        jax.ShapeDtypeStruct((g, b, b), jnp.float32)] * 2
+    return specs, shapes
+
+
+def _sum_gram(dist, dn, c, gram):
+    return (jnp.sum(dist, axis=0)[:, 0], jnp.sum(dn, axis=0)[:, 0],
+            jnp.sum(c, axis=0), jnp.sum(gram, axis=0))
 
 
 def fedagg_norms_batched(x_t: jax.Array, x_stales: jax.Array,
-                         deltas: jax.Array, *, interpret: bool = True):
+                         deltas: jax.Array, *,
+                         interpret: Optional[bool] = None):
     """Batched phase 1 over B concurrent arrivals in ONE grid sweep.
 
     Inputs: x_t (n,), x_stales (B, n), deltas (B, n); n a BLOCK multiple
     (zero-padded by ops.py — padding contributes 0 to every sum).
     Returns (dist0_sq (B,), dn_sq (B,), cross (B, B), gram (B, B)) f32,
     summed over blocks. Each grid step keeps (2B+1) operand tiles resident,
-    so rows-per-step shrinks with B to bound VMEM at the single-delta
-    footprint (~3 * 256 KiB).
+    so rows-per-step shrinks with B past the knee to bound VMEM.
     """
+    interpret = resolve_interpret(interpret)
     b, n = deltas.shape
     assert x_t.shape == (n,) and x_stales.shape == (b, n)
     rows = _batched_rows(b, n, interpret, deltas.dtype.itemsize)
@@ -194,7 +273,8 @@ def fedagg_norms_batched(x_t: jax.Array, x_stales: jax.Array,
     g = n // block
     shaped1 = lambda a: a.reshape(g * rows, LANES)
     shapedb = lambda a: a.reshape(b, g * rows, LANES)
-    dist, dn, c, gram = pl.pallas_call(
+    specs, shapes = _gram_outputs(g, b)
+    out = pl.pallas_call(
         _norms_batched_kernel,
         grid=(g,),
         in_specs=[
@@ -202,41 +282,39 @@ def fedagg_norms_batched(x_t: jax.Array, x_stales: jax.Array,
             pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
             pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, b), lambda i: (i, 0)),
-            pl.BlockSpec((1, b), lambda i: (i, 0)),
-            pl.BlockSpec((1, b, b), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, b, b), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, b), jnp.float32),
-            jax.ShapeDtypeStruct((g, b), jnp.float32),
-            jax.ShapeDtypeStruct((g, b, b), jnp.float32),
-            jax.ShapeDtypeStruct((g, b, b), jnp.float32),
-        ],
+        out_specs=specs,
+        out_shape=shapes,
+        compiler_params=_batched_params(interpret),
         interpret=interpret,
     )(shaped1(x_t), shapedb(x_stales), shapedb(deltas))
-    return (jnp.sum(dist, axis=0), jnp.sum(dn, axis=0),
-            jnp.sum(c, axis=0), jnp.sum(gram, axis=0))
+    return _sum_gram(*out)
+
+
+def _apply_rows(etas_ref, xt_ref, delta, b: int, out_ref):
+    """out = x_t + sum_i etas[i] * delta(i) on one tile, ``delta(i)`` the
+    f32 (rows, LANES) tile of delta i. Accumulated on the VPU in arrival
+    order — the exact f32 arithmetic of B sequential AXPYs, where an MXU
+    ``etas @ deltas`` would round every delta to bf16."""
+    acc = _f32(xt_ref[...])
+    for i in range(b):
+        acc = acc + etas_ref[0, i] * delta(i)
+    out_ref[...] = acc.astype(out_ref.dtype)
 
 
 def _apply_batched_kernel(etas_ref, xt_ref, d_ref, out_ref):
-    etas = etas_ref[...]                            # (1, B) f32
-    xt = _f32(xt_ref[...])                          # (rows, LANES)
-    d = _f32(d_ref[...])                            # (B, rows, LANES)
-    acc = jnp.dot(etas, d.reshape(d.shape[0], -1),
-                  preferred_element_type=jnp.float32)
-    out_ref[...] = (xt + acc.reshape(xt.shape)).astype(out_ref.dtype)
+    _apply_rows(etas_ref, xt_ref, lambda i: _f32(d_ref[i]), d_ref.shape[0],
+                out_ref)
 
 
 def fedagg_apply_batched(x_t: jax.Array, deltas: jax.Array, etas: jax.Array,
-                         *, interpret: bool = True) -> jax.Array:
+                         *, interpret: Optional[bool] = None) -> jax.Array:
     """Batched Eq.(5): x_t + sum_b etas[b] * deltas[b] in ONE grid sweep.
 
     With etas from ``sequential_batch_schedule`` this equals applying the B
     updates one at a time (Eq.(5) is linear in the deltas), while reading
     x_t once instead of B times and writing one output instead of B.
     """
+    interpret = resolve_interpret(interpret)
     b, n = deltas.shape
     assert x_t.shape == (n,) and etas.shape == (b,)
     rows = _batched_rows(b, n, interpret, deltas.dtype.itemsize)
@@ -253,30 +331,31 @@ def fedagg_apply_batched(x_t: jax.Array, deltas: jax.Array, etas: jax.Array,
         ],
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * rows, LANES), x_t.dtype),
+        compiler_params=_batched_params(interpret),
         interpret=interpret,
     )(etas.reshape(1, b).astype(jnp.float32),
       x_t.reshape(g * rows, LANES), deltas.reshape(b, g * rows, LANES))
     return out.reshape(n)
 
 
-def _fused_kernel(scal_ref, xt_ref, xs_ref, d_ref, out_ref, norm_ref):
+def _fused_kernel(scal_ref, xt_ref, xs_ref, d_ref, out_ref, dist_ref,
+                  dn_ref):
     """Beyond-paper single-phase variant for the displacement-GMIS server:
     dist is known a-priori (see DESIGN.md §3), so gamma/eta are computed on
     the host and the whole aggregation is ONE pass: read (x_t, delta),
     write x_{t+1}, and opportunistically emit the partial norms needed for
     the *next* gamma bookkeeping."""
     eta = scal_ref[0, 0]
-    xt = xt_ref[...].astype(jnp.float32)
-    xs = xs_ref[...].astype(jnp.float32)
-    d = d_ref[...].astype(jnp.float32)
+    xt = _f32(xt_ref[...])
+    d = _f32(d_ref[...])
     out_ref[...] = (xt + eta * d).astype(out_ref.dtype)
-    diff = xt - xs
-    norm_ref[0, 0] = jnp.sum(diff * diff)
-    norm_ref[0, 1] = jnp.sum(d * d)
+    diff = xt - _f32(xs_ref[...])
+    dist_ref[...] = _fold(diff * diff)
+    dn_ref[...] = _fold(d * d)
 
 
 def fedagg_fused(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
-                 eta: jax.Array, *, interpret: bool = True):
+                 eta: jax.Array, *, interpret: Optional[bool] = None):
     """One-pass: returns (x_t + eta*delta, (dist^2, ||delta||^2) partials
     summed). Used when eta is precomputed (displacement mode) but the norms
     are still wanted for telemetry/controller."""
@@ -285,7 +364,8 @@ def fedagg_fused(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
     assert n % block == 0, (n, block)
     g = n // block
     shaped = lambda a: a.reshape(g * BLOCK_ROWS, LANES)
-    out, partial = pl.pallas_call(
+    spec, shape = _partials(g)
+    out, dist, dn = pl.pallas_call(
         _fused_kernel,
         grid=(g,),
         in_specs=[
@@ -296,51 +376,68 @@ def fedagg_fused(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i: (i, 0)),
+            spec, spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
-            jax.ShapeDtypeStruct((g, 2), jnp.float32),
+            shape, shape,
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(x_stale),
       shaped(delta))
-    return out.reshape(n), jnp.sum(partial, axis=0)
+    return out.reshape(n), jnp.stack([jnp.sum(dist), jnp.sum(dn)])
 
 
 # ------------------------------------------------- quantization-fused path --
 # Compressed delta transport (DESIGN.md §13): deltas arrive as per-block-
 # scaled int8 (one f32 scale per QBLOCK elements, repro.core.compression)
 # and are dequantized INSIDE the grid step — one upcast + one broadcast
-# multiply per resident tile — so the f32 delta vector is never
+# multiply per scale block — so the f32 delta vector is never
 # materialized in HBM. bf16 deltas need none of this: the f32 kernels
 # above upcast tiles on load, so bf16 rides them unchanged.
+#
+# Scale layout: each grid step reads its tile's scales as one lane-major
+# row, (1, spb) for a single delta and (B, spb) for a batch, from a
+# (steps, 1 or B, spb) array whose block spans the last two dims.
+
+def _scaled(v: jax.Array, s_row: jax.Array) -> jax.Array:
+    """Multiply each QBLOCK_ROWS-row block of the f32 tile ``v`` (rows,
+    LANES) by its scale; ``s_row`` (1, spb) holds one scale per block.
+    The row turns into a column by a small transpose, never by a reshape
+    that moves lanes into sublanes."""
+    spb = s_row.shape[1]
+    col = jnp.transpose(s_row)[:, :, None]             # (spb, 1, 1)
+    return (v.reshape(spb, QBLOCK_ROWS, LANES) * col).reshape(v.shape)
+
 
 def _dequant_tile(q, s):
-    """Dequantize one VMEM tile. ``q`` int8 (rows, LANES) or (B, rows,
-    LANES); ``s`` the matching f32 scales, one per QBLOCK_ROWS rows.
-    Returns the f32 tile(s)."""
-    rows = q.shape[-2]
-    spb = rows // QBLOCK_ROWS              # scale blocks per tile
+    """Dequantize one VMEM tile. ``q`` int8 (rows, LANES) with ``s`` (1,
+    spb), or (B, rows, LANES) with ``s`` (B, spb). Returns the f32
+    tile(s)."""
+    v = q.astype(jnp.float32)
     if q.ndim == 2:
-        v = q.astype(jnp.float32).reshape(spb, QBLOCK)
-        return (v * s.reshape(spb, 1)).reshape(rows, LANES)
-    b = q.shape[0]
-    v = q.astype(jnp.float32).reshape(b, spb, QBLOCK)
-    return (v * s.reshape(b, spb, 1)).reshape(b, rows, LANES)
+        return _scaled(v, s)
+    return jnp.stack([_scaled(v[i], s[i:i + 1]) for i in range(q.shape[0])])
 
 
-def _norms_q_kernel(xt_ref, xs_ref, q_ref, s_ref, out_ref):
-    xt = _f32(xt_ref[...])
-    xs = _f32(xs_ref[...])
-    d = _dequant_tile(q_ref[...], s_ref[...])
-    diff = xt - xs
-    out_ref[0, 0] = jnp.sum(diff * diff)
-    out_ref[0, 1] = jnp.sum(d * d)
+def _scale_rows(scales: jax.Array, g: int) -> jax.Array:
+    """(n // QBLOCK,) scales -> (g, 1, spb); (B, n // QBLOCK) -> (g, B,
+    spb): grid step i reads row block i."""
+    if scales.ndim == 1:
+        return scales.reshape(g, 1, -1)
+    return scales.reshape(scales.shape[0], g, -1).transpose(1, 0, 2)
+
+
+def _norms_q_kernel(xt_ref, xs_ref, q_ref, s_ref, dist_ref, dn_ref):
+    diff = _f32(xt_ref[...]) - _f32(xs_ref[...])
+    d = _dequant_tile(q_ref[...], s_ref[0])
+    dist_ref[...] = _fold(diff * diff)
+    dn_ref[...] = _fold(d * d)
 
 
 def fedagg_norms_q(x_t: jax.Array, x_stale: jax.Array, q: jax.Array,
-                   scales: jax.Array, *, interpret: bool = True) -> jax.Array:
+                   scales: jax.Array, *,
+                   interpret: Optional[bool] = None) -> jax.Array:
     """Quant-fused phase 1: like :func:`fedagg_norms` but the delta arrives
     as int8 ``q`` (n,) + f32 ``scales`` (n // QBLOCK,). The emitted
     ||delta||^2 is the DEQUANTIZED norm — exactly what the AXPY applies, so
@@ -353,31 +450,33 @@ def fedagg_norms_q(x_t: jax.Array, x_stale: jax.Array, q: jax.Array,
     g = n // block
     spb = BLOCK_ROWS // QBLOCK_ROWS
     shaped = lambda a: a.reshape(g * BLOCK_ROWS, LANES)
-    partial = pl.pallas_call(
+    spec, shape = _partials(g)
+    dist, dn = pl.pallas_call(
         _norms_q_kernel,
         grid=(g,),
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, spb), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, spb), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, 2), jnp.float32),
-        interpret=interpret,
-    )(shaped(x_t), shaped(x_stale), shaped(q), scales.reshape(g, spb))
-    return jnp.sum(partial, axis=0)
+        out_specs=[spec, spec],
+        out_shape=[shape, shape],
+        interpret=resolve_interpret(interpret),
+    )(shaped(x_t), shaped(x_stale), shaped(q), _scale_rows(scales, g))
+    return jnp.stack([jnp.sum(dist), jnp.sum(dn)])
 
 
 def _axpy_q_kernel(eta_ref, xt_ref, q_ref, s_ref, out_ref):
     eta = eta_ref[0, 0]
-    d = _dequant_tile(q_ref[...], s_ref[...])
+    d = _dequant_tile(q_ref[...], s_ref[0])
     out_ref[...] = (xt_ref[...].astype(jnp.float32) + eta * d
                     ).astype(out_ref.dtype)
 
 
 def fedagg_axpy_q(x_t: jax.Array, q: jax.Array, scales: jax.Array,
-                  eta: jax.Array, *, interpret: bool = True) -> jax.Array:
+                  eta: jax.Array, *,
+                  interpret: Optional[bool] = None) -> jax.Array:
     """Quant-fused Eq.(5): x_t + eta * dequant(q, scales), one sweep."""
     n = x_t.shape[0]
     block = BLOCK_ROWS * LANES
@@ -393,40 +492,31 @@ def fedagg_axpy_q(x_t: jax.Array, q: jax.Array, scales: jax.Array,
             pl.BlockSpec((1, 1), lambda i: (0, 0)),          # eta broadcast
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, spb), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, spb), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(q),
-      scales.reshape(g, spb))
+      _scale_rows(scales, g))
     return out.reshape(n)
 
 
-def _norms_batched_q_kernel(xt_ref, xs_ref, q_ref, s_ref, dist_ref, dn_ref,
-                            c_ref, g_ref):
-    b = q_ref.shape[0]
-    xt = _f32(xt_ref[...])                          # (rows, LANES)
-    xs = _f32(xs_ref[...])                          # (B, rows, LANES)
-    d = _dequant_tile(q_ref[...], s_ref[...]).reshape(b, -1)
-    drift = (xt[None] - xs).reshape(b, -1)
-    c = jnp.dot(drift, d.T, preferred_element_type=jnp.float32)
-    g = jnp.dot(d, d.T, preferred_element_type=jnp.float32)
-    dist_ref[0, :] = jnp.sum(drift * drift, axis=1)
-    dn_ref[0, :] = jnp.sum(d * d, axis=1)
-    c_ref[0] = c
-    g_ref[0] = g
+def _norms_batched_q_kernel(xt_ref, xs_ref, q_ref, s_ref, *out_refs):
+    _gram_partials(_f32(xt_ref[...]), _f32(xs_ref[...]),
+                   _dequant_tile(q_ref[...], s_ref[0]), *out_refs)
 
 
 def fedagg_norms_batched_q(x_t: jax.Array, x_stales: jax.Array,
                            qs: jax.Array, scales: jax.Array, *,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """Batched phase 1 over B quantized arrivals: like
     :func:`fedagg_norms_batched` with ``qs`` (B, n) int8 + ``scales``
     (B, n // QBLOCK) f32 resident instead of f32 deltas — the delta tiles
     cost 1 byte/element, so the free-batch knee moves from 15 to 24
     (``batched_b_max(1)``). All four outputs are computed on the
     dequantized values."""
+    interpret = resolve_interpret(interpret)
     b, n = qs.shape
     assert x_t.shape == (n,) and x_stales.shape == (b, n)
     assert scales.shape == (b, n // QBLOCK), (scales.shape, b, n // QBLOCK)
@@ -437,47 +527,37 @@ def fedagg_norms_batched_q(x_t: jax.Array, x_stales: jax.Array,
     spb = rows // QBLOCK_ROWS
     shaped1 = lambda a: a.reshape(g * rows, LANES)
     shapedb = lambda a: a.reshape(b, g * rows, LANES)
-    dist, dn, c, gram = pl.pallas_call(
+    specs, shapes = _gram_outputs(g, b)
+    out = pl.pallas_call(
         _norms_batched_q_kernel,
         grid=(g,),
         in_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
             pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
-            pl.BlockSpec((b, 1, spb), lambda i: (0, i, 0)),
+            pl.BlockSpec((1, b, spb), lambda i: (i, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, b), lambda i: (i, 0)),
-            pl.BlockSpec((1, b), lambda i: (i, 0)),
-            pl.BlockSpec((1, b, b), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, b, b), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, b), jnp.float32),
-            jax.ShapeDtypeStruct((g, b), jnp.float32),
-            jax.ShapeDtypeStruct((g, b, b), jnp.float32),
-            jax.ShapeDtypeStruct((g, b, b), jnp.float32),
-        ],
+        out_specs=specs,
+        out_shape=shapes,
+        compiler_params=_batched_params(interpret),
         interpret=interpret,
-    )(shaped1(x_t), shapedb(x_stales), shapedb(qs),
-      scales.reshape(b, g, spb))
-    return (jnp.sum(dist, axis=0), jnp.sum(dn, axis=0),
-            jnp.sum(c, axis=0), jnp.sum(gram, axis=0))
+    )(shaped1(x_t), shapedb(x_stales), shapedb(qs), _scale_rows(scales, g))
+    return _sum_gram(*out)
 
 
 def _apply_batched_q_kernel(etas_ref, xt_ref, q_ref, s_ref, out_ref):
-    etas = etas_ref[...]                            # (1, B) f32
-    xt = _f32(xt_ref[...])                          # (rows, LANES)
-    d = _dequant_tile(q_ref[...], s_ref[...])       # (B, rows, LANES)
-    acc = jnp.dot(etas, d.reshape(d.shape[0], -1),
-                  preferred_element_type=jnp.float32)
-    out_ref[...] = (xt + acc.reshape(xt.shape)).astype(out_ref.dtype)
+    scales = s_ref[0]                               # (B, spb)
+    _apply_rows(etas_ref, xt_ref,
+                lambda i: _scaled(q_ref[i].astype(jnp.float32),
+                                  scales[i:i + 1]),
+                q_ref.shape[0], out_ref)
 
 
 def fedagg_apply_batched_q(x_t: jax.Array, qs: jax.Array, scales: jax.Array,
                            etas: jax.Array, *,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """Batched quant-fused Eq.(5): x_t + sum_b etas[b] * dequant(qs[b])."""
+    interpret = resolve_interpret(interpret)
     b, n = qs.shape
     assert x_t.shape == (n,) and etas.shape == (b,)
     assert scales.shape == (b, n // QBLOCK)
@@ -493,12 +573,13 @@ def fedagg_apply_batched_q(x_t: jax.Array, qs: jax.Array, scales: jax.Array,
             pl.BlockSpec((1, b), lambda i: (0, 0)),          # etas broadcast
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
-            pl.BlockSpec((b, 1, spb), lambda i: (0, i, 0)),
+            pl.BlockSpec((1, b, spb), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * rows, LANES), x_t.dtype),
+        compiler_params=_batched_params(interpret),
         interpret=interpret,
     )(etas.reshape(1, b).astype(jnp.float32),
       x_t.reshape(g * rows, LANES), qs.reshape(b, g * rows, LANES),
-      scales.reshape(b, g, spb))
+      _scale_rows(scales, g))
     return out.reshape(n)

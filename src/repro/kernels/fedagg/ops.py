@@ -14,7 +14,7 @@ Two API levels:
 from __future__ import annotations
 
 import functools
-from typing import Any, List, Sequence, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,13 +44,15 @@ def _pad_flat(tree: PyTree) -> jax.Array:
 
 # ---------------------------------------------------------------- flat API --
 # The flat entry points are jit-cached: the server calls them once per
-# arrival with fixed shapes, so tracing/lowering the interpret-mode grid
-# happens once per (shape, batch) instead of per update.
+# arrival with fixed shapes, so tracing/lowering the kernel grid happens
+# once per (shape, batch) instead of per update. ``interpret=None`` (the
+# default everywhere) lets the kernels pick their mode from the platform
+# at trace time (``fedagg.resolve_interpret``).
 
 @functools.partial(jax.jit, static_argnames=("lam", "eps", "cap", "interpret"))
 def flat_aggregate(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array, *,
                    lam: float, eps: float, cap: float = 0.0,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """One Eq.(5-7) step on padded flat vectors: a norms sweep, scalar
     gamma/eta, an AXPY sweep. Returns (new_vec, gamma, eta, dist, dnorm)."""
     sq = fedagg.fedagg_norms(x_t, x_stale, delta, interpret=interpret)
@@ -63,7 +65,7 @@ def flat_aggregate(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array, *,
 def flat_aggregate_displacement(x_t: jax.Array, disp: jax.Array,
                                 delta: jax.Array, zeros: jax.Array, *,
                                 lam: float, eps: float, cap: float = 0.0,
-                                interpret: bool = True):
+                                interpret: Optional[bool] = None):
     """Displacement-GMIS variant (DESIGN.md §3): the stale model is never
     materialized; ``disp`` = x_t - x_{t-tau} is maintained incrementally, so
     one norms sweep over (disp, delta) — with a cached ``zeros`` vector in
@@ -83,7 +85,7 @@ _apply_batched = jax.jit(fedagg.fedagg_apply_batched,
 
 def flat_aggregate_batched(x_t: jax.Array, x_stales: jax.Array,
                            deltas: jax.Array, *, lam: float, eps: float,
-                           cap: float = 0.0, interpret: bool = True,
+                           cap: float = 0.0, interpret: Optional[bool] = None,
                            screen=None):
     """B concurrent arrivals in two grid sweeps, sequential-equivalent to B
     one-at-a-time ``flat_aggregate`` calls (see
@@ -125,7 +127,7 @@ def flat_aggregate_batched(x_t: jax.Array, x_stales: jax.Array,
 @functools.partial(jax.jit, static_argnames=("lam", "eps", "cap", "interpret"))
 def flat_aggregate_q(x_t: jax.Array, x_stale: jax.Array, q: jax.Array,
                      scales: jax.Array, *, lam: float, eps: float,
-                     cap: float = 0.0, interpret: bool = True):
+                     cap: float = 0.0, interpret: Optional[bool] = None):
     """Quant-fused Eq.(5-7) step. The emitted dnorm is the dequantized
     delta norm — exactly what the AXPY applies."""
     sq = fedagg.fedagg_norms_q(x_t, x_stale, q, scales, interpret=interpret)
@@ -138,7 +140,8 @@ def flat_aggregate_q(x_t: jax.Array, x_stale: jax.Array, q: jax.Array,
 def flat_aggregate_displacement_q(x_t: jax.Array, disp: jax.Array,
                                   q: jax.Array, scales: jax.Array,
                                   zeros: jax.Array, *, lam: float, eps: float,
-                                  cap: float = 0.0, interpret: bool = True):
+                                  cap: float = 0.0,
+                                  interpret: Optional[bool] = None):
     """Displacement-GMIS variant of :func:`flat_aggregate_q`."""
     sq = fedagg.fedagg_norms_q(disp, zeros, q, scales, interpret=interpret)
     gamma, eta, dist, dnorm = gamma_eta_from_sq(sq[0], sq[1], lam, eps, cap)
@@ -155,7 +158,7 @@ _apply_batched_q = jax.jit(fedagg.fedagg_apply_batched_q,
 def flat_aggregate_batched_q(x_t: jax.Array, x_stales: jax.Array,
                              qs: jax.Array, qscales: jax.Array, *,
                              lam: float, eps: float, cap: float = 0.0,
-                             interpret: bool = True, screen=None):
+                             interpret: Optional[bool] = None, screen=None):
     """Quant-fused twin of :func:`flat_aggregate_batched`: B int8 arrivals
     (qs (B, n) + qscales (B, n // QBLOCK)) drained in two grid sweeps.
     The screening decider sees the kernel-emitted DEQUANTIZED norms, and
@@ -179,7 +182,8 @@ def flat_aggregate_batched_q(x_t: jax.Array, x_stales: jax.Array,
 @functools.partial(jax.jit, static_argnames=("lam", "eps", "cap", "interpret"))
 def asyncfeded_aggregate_pallas(x_t: PyTree, x_stale: PyTree, delta: PyTree,
                                 *, lam: float, eps: float, cap: float = 0.0,
-                                interpret: bool = True) -> AggregationResult:
+                                interpret: Optional[bool] = None
+                                ) -> AggregationResult:
     xt = _pad_flat(x_t)
     xs = _pad_flat(x_stale)
     d = _pad_flat(delta)
@@ -192,8 +196,8 @@ def asyncfeded_aggregate_pallas(x_t: PyTree, x_stale: PyTree, delta: PyTree,
 
 def asyncfeded_aggregate_batched_pallas(
         x_t: PyTree, x_stales: Sequence[PyTree], deltas: Sequence[PyTree], *,
-        lam: float, eps: float, cap: float = 0.0, interpret: bool = True
-) -> Tuple[PyTree, Any, Any, Any, Any]:
+        lam: float, eps: float, cap: float = 0.0,
+        interpret: Optional[bool] = None) -> Tuple[PyTree, Any, Any, Any, Any]:
     """Batched pytree entry point: stacks B (stale, delta) pairs and drains
     them through the multi-delta kernels. Returns
     (new_params, etas, gammas, dists, dnorms). Not jitted — the

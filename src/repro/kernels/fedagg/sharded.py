@@ -23,13 +23,14 @@ versus the replicated single-grid sweep, so results match to float
 tolerance (observed ~2e-5 relative), not bit-exactly — the same class
 of difference the cohort engines pin with rtol=2e-5.
 
-``check_rep=False`` on every shard_map is load-bearing: interpret-mode
-``pallas_call`` has no replication rule, so shard_map's replication
-checker rejects the body otherwise.
+``check_vma=False`` on every ``jax.shard_map`` is load-bearing: a
+``pallas_call`` has no varying-manual-axes rule, so the checker rejects
+the body otherwise.
 
 Entry points mirror `ops.py` signatures plus a ``shards`` kwarg; all
-dispatches are cached per (shards, scalars, interpret) so the server
-traces once per shape.
+dispatches are cached per (shards, scalars, kernel mode) so the server
+traces once per shape. The kernel mode is resolved from the platform when
+the call is made (``fedagg.resolve_interpret``).
 """
 from __future__ import annotations
 
@@ -38,7 +39,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.core.aggregation import (gamma_eta_from_sq,
@@ -51,6 +51,7 @@ from repro.sharding.specs import (FLAT_SCALES_SPEC, FLAT_STACKED_SCALES_SPEC,
 
 #: replicated operands/outputs (scalars, eta rows) on the (pod, model) mesh
 _REP = PartitionSpec()
+_mode = fedagg.resolve_interpret
 
 
 @functools.lru_cache(maxsize=None)
@@ -68,9 +69,9 @@ def place_flat(vec: jax.Array, shards: int) -> jax.Array:
 
 
 def _smap(body, shards, in_specs, out_specs):
-    return jax.jit(shard_map(body, mesh=fedagg_mesh(shards),
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=fedagg_mesh(shards),
+                                 in_specs=in_specs, out_specs=out_specs,
+                                 check_vma=False))
 
 
 # ------------------------------------------------------- single-update --
@@ -90,12 +91,12 @@ def _aggregate(shards, lam, eps, cap, interpret):
 
 
 def flat_aggregate(x_t, x_stale, delta, *, lam, eps, cap=0.0, shards,
-                   interpret=True):
+                   interpret=None):
     """Sharded twin of ``ops.flat_aggregate``: one Eq.(5-7) dispatch, one
     cross-shard psum. Returns (new_vec [model-sharded], gamma, eta, dist,
     dnorm)."""
     return _aggregate(int(shards), float(lam), float(eps), float(cap),
-                      bool(interpret))(x_t, x_stale, delta)
+                      _mode(interpret))(x_t, x_stale, delta)
 
 
 @functools.lru_cache(maxsize=None)
@@ -113,10 +114,10 @@ def _aggregate_displacement(shards, lam, eps, cap, interpret):
 
 
 def flat_aggregate_displacement(x_t, disp, delta, zeros, *, lam, eps,
-                                cap=0.0, shards, interpret=True):
+                                cap=0.0, shards, interpret=None):
     """Sharded twin of ``ops.flat_aggregate_displacement``."""
     return _aggregate_displacement(int(shards), float(lam), float(eps),
-                                   float(cap), bool(interpret))(
+                                   float(cap), _mode(interpret))(
         x_t, disp, delta, zeros)
 
 
@@ -141,11 +142,11 @@ def _aggregate_q(shards, lam, eps, cap, interpret):
 
 
 def flat_aggregate_q(x_t, x_stale, q, scales, *, lam, eps, cap=0.0,
-                     shards, interpret=True):
+                     shards, interpret=None):
     """Sharded twin of ``ops.flat_aggregate_q``: the int8 payload is
     dequantized per grid tile inside each shard, norms psum once."""
     return _aggregate_q(int(shards), float(lam), float(eps), float(cap),
-                        bool(interpret))(x_t, x_stale, q, scales)
+                        _mode(interpret))(x_t, x_stale, q, scales)
 
 
 @functools.lru_cache(maxsize=None)
@@ -166,10 +167,10 @@ def _aggregate_displacement_q(shards, lam, eps, cap, interpret):
 
 
 def flat_aggregate_displacement_q(x_t, disp, q, scales, zeros, *, lam, eps,
-                                  cap=0.0, shards, interpret=True):
+                                  cap=0.0, shards, interpret=None):
     """Sharded twin of ``ops.flat_aggregate_displacement_q``."""
     return _aggregate_displacement_q(int(shards), float(lam), float(eps),
-                                     float(cap), bool(interpret))(
+                                     float(cap), _mode(interpret))(
         x_t, disp, q, scales, zeros)
 
 
@@ -202,11 +203,11 @@ def _apply_batched(shards, interpret):
 
 
 def flat_aggregate_batched(x_t, x_stales, deltas, *, lam, eps, cap=0.0,
-                           shards, interpret=True, screen=None):
+                           shards, interpret=None, screen=None):
     """Sharded twin of ``ops.flat_aggregate_batched``: B concurrent
     arrivals, one psum of the (B,)/(B,B) Gram partials, host schedule,
     shard-local apply. Same return signature (new_vec is model-sharded)."""
-    d0, dn_sq, cross, gram = _norms_batched(int(shards), bool(interpret))(
+    d0, dn_sq, cross, gram = _norms_batched(int(shards), _mode(interpret))(
         x_t, x_stales, deltas)
     scales = None
     if screen is not None:
@@ -214,7 +215,7 @@ def flat_aggregate_batched(x_t, x_stales, deltas, *, lam, eps, cap=0.0,
         scales = screen(dns.astype(np.float32))
     etas, gammas, dists, dnorms = sequential_batch_schedule(
         d0, dn_sq, cross, gram, lam=lam, eps=eps, cap=cap, scales=scales)
-    new = _apply_batched(int(shards), bool(interpret))(
+    new = _apply_batched(int(shards), _mode(interpret))(
         x_t, deltas, jnp.asarray(etas))
     return new, etas, gammas, dists, dnorms, scales
 
@@ -245,11 +246,11 @@ def _apply_batched_q(shards, interpret):
 
 
 def flat_aggregate_batched_q(x_t, x_stales, qs, qscales, *, lam, eps,
-                             cap=0.0, shards, interpret=True, screen=None):
+                             cap=0.0, shards, interpret=None, screen=None):
     """Sharded twin of ``ops.flat_aggregate_batched_q``: int8 rows
     dequantize per grid tile inside each shard; the screening decider
     sees the psum'd (global) dequantized norms."""
-    d0, dn_sq, cross, gram = _norms_batched_q(int(shards), bool(interpret))(
+    d0, dn_sq, cross, gram = _norms_batched_q(int(shards), _mode(interpret))(
         x_t, x_stales, qs, qscales)
     scales = None
     if screen is not None:
@@ -257,6 +258,6 @@ def flat_aggregate_batched_q(x_t, x_stales, qs, qscales, *, lam, eps,
         scales = screen(dns.astype(np.float32))
     etas, gammas, dists, dnorms = sequential_batch_schedule(
         d0, dn_sq, cross, gram, lam=lam, eps=eps, cap=cap, scales=scales)
-    new = _apply_batched_q(int(shards), bool(interpret))(
+    new = _apply_batched_q(int(shards), _mode(interpret))(
         x_t, qs, qscales, jnp.asarray(etas))
     return new, etas, gammas, dists, dnorms, scales

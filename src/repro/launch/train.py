@@ -28,6 +28,7 @@ import time
 from repro import configs
 from repro.core import tasks
 from repro.core.simulator import FederatedSimulation
+from repro.utils.xla import enable_compile_cache
 
 
 def run_paper(task_name: str, algorithm: str, max_time: float, seed: int,
@@ -70,7 +71,7 @@ def run_arch_federated(arch: str, steps: int = 20, num_clients: int = 4,
     end of run (so e.g. a FedBuff comparison never drops its partial
     buffer). ``steps`` bounds the number of aggregated updates.
     ``use_pallas_agg`` routes aggregation through the flat-state fedagg
-    kernel backend (interpret mode on CPU).
+    kernel backend (compiled on TPU, interpreted on CPU).
     """
     task = tasks.arch_task(arch, seq_len=seq_len, global_batch=global_batch,
                            num_layers=num_layers, d_model=d_model)
@@ -125,6 +126,7 @@ def main() -> None:
                     help="per-dispatch cohort budget (0 = unlimited)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "paper":
         out = run_paper(args.task, args.algorithm, args.max_time, args.seed,
                         args.suspension_prob)

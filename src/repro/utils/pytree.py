@@ -73,6 +73,12 @@ def tree_zeros_like(a: PyTree) -> PyTree:
     return jax.tree.map(jnp.zeros_like, a)
 
 
+def tree_zeros_host(a: PyTree) -> PyTree:
+    """Host (numpy) zeros shaped like ``a``: rows of a stack assembled on
+    the host never need a device copy first."""
+    return jax.tree.map(lambda l: np.zeros(l.shape, l.dtype), a)
+
+
 def tree_size(a: PyTree) -> int:
     return int(sum(np.prod(l.shape) for l in jax.tree.leaves(a)))
 
