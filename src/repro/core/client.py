@@ -22,6 +22,7 @@ from repro.configs.base import FedConfig
 from repro.core import compression, tasks
 from repro.core.server import ClientUpdate
 from repro.utils import pytree as pt
+from repro.utils import trace
 
 PyTree = Any
 
@@ -112,15 +113,20 @@ class Client:
             self._mu = pt.tree_zeros_like(params)
         # next_stacked(k) is RNG-state-identical to k next() calls (pinned
         # by tests/test_cohort.py), so loop and cohort engines share streams
-        bx, by = self.batcher.next_stacked(k)
+        with trace.span("client.stage",
+                        h2d_bytes=lambda: trace.host_nbytes(bx, by)):
+            bx, by = self.batcher.next_stacked(k)
+            xs = jax.tree.map(jnp.asarray, bx)
+            ys = jax.tree.map(jnp.asarray, by)
         delta, self._mu, loss = _local_k_steps(
-            self.task, params, self._mu, jax.tree.map(jnp.asarray, bx),
-            jax.tree.map(jnp.asarray, by), jnp.float32(self._lr()),
+            self.task, params, self._mu, xs, ys, jnp.float32(self._lr()),
             beta=self.fed.local_momentum, prox_mu=prox_mu)
         self.round_idx += 1
         upd = ClientUpdate(self.client_id, snapshot_iter, k, delta,
                            self.num_samples)
-        return upd, float(loss)
+        with trace.span("client.sync", reads=1,
+                        d2h_bytes=lambda: loss.nbytes):
+            return upd, float(loss)
 
     # --- compressed transport (DESIGN.md §13) ---
     def compress_update(self, upd: ClientUpdate) -> ClientUpdate:

@@ -85,6 +85,7 @@ from repro.core.server import ClientUpdate
 from repro.launch import mesh as mesh_lib
 from repro.sharding import specs as sh
 from repro.utils import pytree as pt
+from repro.utils import trace
 
 PyTree = Any
 
@@ -360,21 +361,27 @@ def _run_chunk(task, fed, engine: str, p_src, mus, lrs_list, x_rows,
         rows += [np.zeros_like(rows[0])] * (c_pad - c_real)
         res_stacked = np.stack(rows)
 
-    xs = _np_stack(*xs_rows)
-    ys = _np_stack(*ys_rows)
-    mu_stacked = _np_stack(*mus)
-    if isinstance(p_src, list):
-        p_stacked = _np_stack(*(p_src + [template] * (c_pad - c_real)))
-    else:                              # shared snapshot: broadcast on device
-        p_stacked = jax.tree.map(
-            lambda p: jnp.broadcast_to(p, (c_pad,) + p.shape), p_src)
+    # the host arrays staged here are uploaded by the core's dispatch
+    with trace.span("client.stage", h2d_bytes=lambda: trace.host_nbytes(
+            xs, ys, mu_stacked, p_stacked, lrs, mask)):
+        xs = _np_stack(*xs_rows)
+        ys = _np_stack(*ys_rows)
+        mu_stacked = _np_stack(*mus)
+        if isinstance(p_src, list):
+            p_stacked = _np_stack(*(p_src + [template] * (c_pad - c_real)))
+        else:                          # shared snapshot: broadcast on device
+            p_stacked = jax.tree.map(
+                lambda p: jnp.broadcast_to(p, (c_pad,) + p.shape), p_src)
 
     if k_chunk is None or k_chunk >= k_pad:
         deltas, new_mu, losses = _core_call(task, engine, fed, p_stacked,
                                             mu_stacked, xs, ys, lrs, mask,
                                             prox_mu, c_pad)
         if wire_mode is None:
-            return (*jax.device_get((deltas, new_mu, losses)), None)
+            out = (deltas, new_mu, losses)
+            with trace.span("client.sync", reads=1,
+                            d2h_bytes=lambda: trace.nbytes(out)):
+                return (*jax.device_get(out), None)
         wire_out = _wire_finish(deltas, res_stacked, wire_mode, c_pad)
         return None, *jax.device_get((new_mu, losses)), wire_out
 
@@ -468,13 +475,14 @@ def run_cohort(task, clients: Sequence,
     # momentum staging happen identically under every plan/engine, so the
     # RNG streams can never fork on a memory fallback
     mus, lrs_list, x_rows, y_rows = [], [], [], []
-    for c, k in zip(clients, ks):
-        mu, lr = c.stage_cohort(template)
-        bx, by = c.batcher.next_stacked(k)
-        mus.append(mu)
-        lrs_list.append(lr)
-        x_rows.append(bx)
-        y_rows.append(by)
+    with trace.span("client.stage"):
+        for c, k in zip(clients, ks):
+            mu, lr = c.stage_cohort(template)
+            bx, by = c.batcher.next_stacked(k)
+            mus.append(mu)
+            lrs_list.append(lr)
+            x_rows.append(bx)
+            y_rows.append(by)
 
     fed = clients[0].fed
     width = c_real

@@ -28,6 +28,8 @@ import dataclasses
 import heapq
 from typing import Any, Callable, List, Optional, Sequence, Union
 
+from repro.utils import trace
+
 
 #: payload sentinel marking a population *check-in* event (DESIGN.md §12):
 #: an anonymous client from the population contacts the server to start a
@@ -298,5 +300,6 @@ class EventLoop:
                 self.clock.advance_to(batch[-1].time)
             self.controller.observe([b.time for b in batch])
             self.drains += 1
-            handle_batch(self.clock.now, batch)
+            with trace.span("loop.drain", B=len(batch)):
+                handle_batch(self.clock.now, batch)
         return min(self.clock.now, self.max_time)

@@ -28,6 +28,7 @@ from repro.core.gmis import DisplacementGMIS, RingGMIS
 from repro.core import compression, screening
 from repro.kernels.fedagg import ops
 from repro.utils import pytree as pt
+from repro.utils import trace
 
 PyTree = Any
 
@@ -247,8 +248,11 @@ class AsyncFedEDServer(AsyncServer):
                 # would stay committed to the fedagg mesh and clash with
                 # whatever mesh a cohort fan-out stacks them onto — so
                 # unflatten from a neutral host copy instead
-                self._flat._tree_cache = self._flat.spec.unflatten(
-                    jnp.asarray(jax.device_get(self._flat.vec)))
+                vec = self._flat.vec
+                with trace.span("server.unflatten",
+                                d2h_bytes=lambda: vec.nbytes):
+                    self._flat._tree_cache = self._flat.spec.unflatten(
+                        jnp.asarray(jax.device_get(vec)))
             return self._flat.tree       # lazily unflattened, cached
         return self._params
 
@@ -393,8 +397,10 @@ class AsyncFedEDServer(AsyncServer):
             return gamma, eta, dist, dnorm, d
         # bf16 payloads ride the f32 kernels unchanged (tiles upcast on
         # load, f32 accumulation), so only the operand swaps
-        d = (self._wire_padded(cd)[0] if cd is not None
-             else self._flat.spec.flatten(upd.delta))
+        with trace.span("server.flatten",
+                        h2d_bytes=lambda: trace.host_nbytes(upd.delta)):
+            d = (self._wire_padded(cd)[0] if cd is not None
+                 else self._flat.spec.flatten(upd.delta))
         if self.gmis_mode == "displacement":
             new_vec, gamma, eta, dist, dnorm = (
                 self._agg["flat_aggregate_displacement"](
@@ -404,9 +410,11 @@ class AsyncFedEDServer(AsyncServer):
             self.gmis.release(upd.client_id)
         else:
             stale, _ = self.gmis.get(upd.snapshot_iter)
-            new_vec, gamma, eta, dist, dnorm = self._agg["flat_aggregate"](
-                self._flat.vec, stale, d, lam=fed.lam, eps=fed.eps,
-                cap=fed.staleness_cap, interpret=self._interpret)
+            with trace.span("server.kernels"):
+                new_vec, gamma, eta, dist, dnorm = (
+                    self._agg["flat_aggregate"](
+                        self._flat.vec, stale, d, lam=fed.lam, eps=fed.eps,
+                        cap=fed.staleness_cap, interpret=self._interpret))
         self._flat = self._flat.replace(new_vec)
         return gamma, eta, dist, dnorm, d
 
@@ -442,19 +450,22 @@ class AsyncFedEDServer(AsyncServer):
         # telemetry so cross-server staleness records are comparable
         lag = self.t - upd.snapshot_iter
         self.t += 1
-        self.gmis.append(self.t, self._gmis_state())
-        self.gmis.on_aggregate(eta, delta)
-        gamma = float(gamma)
-        k_next = self.kctl.observe(upd.client_id, gamma)
-        # history semantics under screening: eta is the effective
-        # multiplier on the RAW arriving delta (eta * clip scale),
-        # delta_norm the raw screening statistic; both collapse to the
-        # plain aggregation scalars when screening is off
-        self.history.append(UpdateRecord(
-            self.t, upd.client_id, lag, gamma,
-            float(eta) * scale, upd.k_used, k_next, float(dist),
-            float(dnorm) if raw_norm is None else raw_norm, verdict))
-        self._register(upd.client_id)
+        # the host waits here for the aggregation's scalars
+        with trace.span("server.sync", reads=3 + (raw_norm is None)):
+            gamma_h, eta_h, dist_h = float(gamma), float(eta), float(dist)
+            dnorm_h = float(dnorm) if raw_norm is None else raw_norm
+        with trace.span("server.book"):
+            self.gmis.append(self.t, self._gmis_state())
+            self.gmis.on_aggregate(eta, delta)
+            k_next = self.kctl.observe(upd.client_id, gamma_h)
+            # history semantics under screening: eta is the effective
+            # multiplier on the RAW arriving delta (eta * clip scale),
+            # delta_norm the raw screening statistic; both collapse to the
+            # plain aggregation scalars when screening is off
+            self.history.append(UpdateRecord(
+                self.t, upd.client_id, lag, gamma_h, eta_h * scale,
+                upd.k_used, k_next, dist_h, dnorm_h, verdict))
+            self._register(upd.client_id)
         return ServerReply(self.params, self.t, k_next)
 
     def on_update_batch(self, upds: List[ClientUpdate]) -> List[ServerReply]:
@@ -466,29 +477,38 @@ class AsyncFedEDServer(AsyncServer):
         sequential default."""
         modes = {u.delta.mode if compression.is_compressed(u.delta)
                  else "off" for u in upds}
-        if (self.backend != "pallas" or self.gmis_mode != "ring"
-                or len(upds) == 1 or len(modes) > 1
-                or getattr(self.screen, "needs_vector", False)):
-            # direction screens (cosine) consume the delta VECTOR, which
-            # the batched Gram sweep never materializes per-update — they
-            # drain sequentially through on_update's vector-aware path
-            replies = [self.on_update(u) for u in upds]
-            if len(replies) > 1:
-                # Every drained client resumes from the window's FINAL
-                # model, so re-anchor their snapshot registrations there —
-                # in displacement mode on_update zeroed each accumulator at
-                # an intermediate model and then folded the remaining batch
-                # updates into it, which would charge clients drift they
-                # never experienced.
-                for u in upds:
-                    self._register(u.client_id)
-                replies = [ServerReply(self.params, self.t, r.k_next)
-                           for r in replies]
-            return replies
+        # direction screens (cosine) consume the delta VECTOR, which the
+        # batched Gram sweep never materializes per-update — they drain
+        # sequentially through on_update's vector-aware path
+        sequential = (self.backend != "pallas" or self.gmis_mode != "ring"
+                      or len(upds) == 1 or len(modes) > 1
+                      or getattr(self.screen, "needs_vector", False))
+        with trace.span("server.drain", B=len(upds),
+                        path="seq" if sequential else "batched"):
+            if sequential:
+                return self._drain_sequential(upds)
+            return self._drain_batched(upds, modes.pop())
+
+    def _drain_sequential(self, upds: List[ClientUpdate]
+                          ) -> List[ServerReply]:
+        replies = [self.on_update(u) for u in upds]
+        if len(replies) > 1:
+            # Every drained client resumes from the window's FINAL model,
+            # so re-anchor their snapshot registrations there — in
+            # displacement mode on_update zeroed each accumulator at an
+            # intermediate model and then folded the remaining batch
+            # updates into it, which would charge clients drift they never
+            # experienced.
+            for u in upds:
+                self._register(u.client_id)
+            replies = [ServerReply(self.params, self.t, r.k_next)
+                       for r in replies]
+        return replies
+
+    def _drain_batched(self, upds: List[ClientUpdate], mode: str
+                       ) -> List[ServerReply]:
         fed = self.fed
         spec = self._flat.spec
-        mode = modes.pop()
-        stales = jnp.stack([self.gmis.get(u.snapshot_iter)[0] for u in upds])
         # screening reuses the batched Gram sweep: the kernel-emitted raw
         # delta norms feed NormScreen in arrival order, and the returned
         # scale factors fold into the sequential-equivalence schedule
@@ -498,6 +518,18 @@ class AsyncFedEDServer(AsyncServer):
         screen_fn = (None if self.screen is None else
                      lambda dns: self.screen.decide_batch(
                          dns, [u.client_id for u in upds]))
+        with trace.span("server.flatten",
+                        h2d_bytes=lambda: trace.host_nbytes(
+                            [u.delta for u in upds])):
+            stales = jnp.stack([self.gmis.get(u.snapshot_iter)[0]
+                                for u in upds])
+            if mode != "int8":
+                # "off" flattens pytrees; "bf16" stacks the bf16 payloads
+                # straight through the f32 kernels (tiles upcast on load)
+                deltas = jnp.stack([self._wire_padded(u.delta)[0]
+                                    if mode == "bf16"
+                                    else spec.flatten(u.delta)
+                                    for u in upds])
         if mode == "int8":
             wires = [self._wire_padded(u.delta) for u in upds]
             qs = jnp.stack([q for q, _ in wires])
@@ -508,46 +540,43 @@ class AsyncFedEDServer(AsyncServer):
                     eps=fed.eps, cap=fed.staleness_cap,
                     interpret=self._interpret, screen=screen_fn))
         else:
-            # "off" flattens pytrees; "bf16" stacks the bf16 payloads
-            # straight through the f32 kernels (tiles upcast on load)
-            deltas = jnp.stack([self._wire_padded(u.delta)[0]
-                                if mode == "bf16"
-                                else spec.flatten(u.delta) for u in upds])
-            new_vec, etas, gammas, dists, dnorms, scales = (
-                self._agg["flat_aggregate_batched"](
-                    self._flat.vec, stales, deltas, lam=fed.lam,
-                    eps=fed.eps, cap=fed.staleness_cap,
-                    interpret=self._interpret, screen=screen_fn))
+            with trace.span("server.kernels"):
+                new_vec, etas, gammas, dists, dnorms, scales = (
+                    self._agg["flat_aggregate_batched"](
+                        self._flat.vec, stales, deltas, lam=fed.lam,
+                        eps=fed.eps, cap=fed.staleness_cap,
+                        interpret=self._interpret, screen=screen_fn))
         self._flat = self._flat.replace(new_vec)
         k_nexts = []
-        for i, upd in enumerate(upds):
-            verdict = ("accept" if scales is None
-                       else screening.verdict_of_scale(float(scales[i])))
-            # pre-increment staleness tau, exactly as in on_update: the
-            # server state at this update's turn in the sequential
-            # equivalence, before its own increment
-            lag = self.t - upd.snapshot_iter
-            if verdict == "reject":
-                k_next = self.kctl.get(upd.client_id)
-                self.history.append(UpdateRecord(
-                    self.t, upd.client_id, lag, float("nan"), 0.0,
-                    upd.k_used, k_next, float("nan"), float(dnorms[i]),
-                    "reject"))
-            else:
-                self.t += 1
-                gamma = float(gammas[i])
-                k_next = self.kctl.observe(upd.client_id, gamma)
-                self.history.append(UpdateRecord(
-                    self.t, upd.client_id, lag, gamma,
-                    float(etas[i]), upd.k_used, k_next, float(dists[i]),
-                    float(dnorms[i]), verdict))
-            k_nexts.append(k_next)
-        # Intermediate models x_{t+1}..x_{t+B-1} are never handed to any
-        # client (every drained client resumes from the window's final
-        # model), so only the final version enters the GMIS.
-        self.gmis.append(self.t, self._gmis_state())
-        for upd in upds:
-            self._register(upd.client_id)
+        with trace.span("server.book"):
+            for i, upd in enumerate(upds):
+                verdict = ("accept" if scales is None
+                           else screening.verdict_of_scale(float(scales[i])))
+                # pre-increment staleness tau, exactly as in on_update:
+                # the server state at this update's turn in the
+                # sequential equivalence, before its own increment
+                lag = self.t - upd.snapshot_iter
+                if verdict == "reject":
+                    k_next = self.kctl.get(upd.client_id)
+                    self.history.append(UpdateRecord(
+                        self.t, upd.client_id, lag, float("nan"), 0.0,
+                        upd.k_used, k_next, float("nan"), float(dnorms[i]),
+                        "reject"))
+                else:
+                    self.t += 1
+                    gamma = float(gammas[i])
+                    k_next = self.kctl.observe(upd.client_id, gamma)
+                    self.history.append(UpdateRecord(
+                        self.t, upd.client_id, lag, gamma,
+                        float(etas[i]), upd.k_used, k_next, float(dists[i]),
+                        float(dnorms[i]), verdict))
+                k_nexts.append(k_next)
+            # Intermediate models x_{t+1}..x_{t+B-1} are never handed to
+            # any client (every drained client resumes from the window's
+            # final model), so only the final version enters the GMIS.
+            self.gmis.append(self.t, self._gmis_state())
+            for upd in upds:
+                self._register(upd.client_id)
         return [ServerReply(self.params, self.t, k) for k in k_nexts]
 
     def batch_limit(self) -> Optional[int]:

@@ -56,6 +56,7 @@ from repro.core.events import (CHECKIN, EventLoop, VirtualClock,
                                make_window_controller)
 from repro.core.server import ClientUpdate, ServerReply, make_server
 from repro.utils import pytree as pt
+from repro.utils import trace
 
 PyTree = Any
 
@@ -222,8 +223,9 @@ class FederatedSimulation:
 
     # --------------------------------------------------------------- eval --
     def _eval_point(self, time: float) -> EvalPoint:
-        acc, loss = self._eval(self.server.params)
-        return EvalPoint(time, self.server.t, float(acc), float(loss))
+        with trace.span("loop.eval", reads=2):
+            acc, loss = self._eval(self.server.params)
+            return EvalPoint(time, self.server.t, float(acc), float(loss))
 
     def _plan_dict(self) -> Optional[dict]:
         return None if self.cohort_plan is None else self.cohort_plan.to_dict()
@@ -249,14 +251,19 @@ class FederatedSimulation:
         the fan-out to the loop engine when even a 2-client chunk
         overflows.
         """
-        if self.fed.client_engine in cohort.COHORT_ENGINES and len(jobs) > 1:
-            ks = [r.k_next for _, r in jobs]
-            plan = budget_mod.plan_cohort(
-                self.task, self.fed, clients=len(jobs), k=max(ks),
-                param_bytes=self.model_bytes, prox_mu=self.prox_mu,
-                ragged=len(set(ks)) > 1)
-            self.cohort_plan = plan
-            if plan.engine != "loop":
+        engine = "loop"
+        with trace.span("client.fanout", jobs=len(jobs),
+                        engine=lambda: engine):
+            if (self.fed.client_engine in cohort.COHORT_ENGINES
+                    and len(jobs) > 1):
+                ks = [r.k_next for _, r in jobs]
+                plan = budget_mod.plan_cohort(
+                    self.task, self.fed, clients=len(jobs), k=max(ks),
+                    param_bytes=self.model_bytes, prox_mu=self.prox_mu,
+                    ragged=len(set(ks)) > 1)
+                self.cohort_plan = plan
+                engine = plan.engine
+            if engine != "loop":
                 # run_cohort collapses identical snapshot objects to the
                 # broadcast fast path itself (every server path hands a
                 # burst one shared model object)
@@ -264,10 +271,10 @@ class FederatedSimulation:
                     self.task, [c for c, _ in jobs],
                     [r.params for _, r in jobs], ks,
                     [r.iteration for _, r in jobs], prox_mu=self.prox_mu,
-                    per_client_params=True, engine=plan.engine, plan=plan)
+                    per_client_params=True, engine=engine, plan=plan)
                 return [u for u, _ in out]
-        return [c.run_local(r.params, r.k_next, r.iteration, self.prox_mu)[0]
-                for c, r in jobs]
+            return [c.run_local(r.params, r.k_next, r.iteration,
+                                self.prox_mu)[0] for c, r in jobs]
 
     def _dispatch(self, loop: EventLoop, now: float,
                   jobs: List[Tuple[Client, ServerReply]]) -> int:

@@ -166,6 +166,7 @@ def fedagg_norms(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
         in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))] * 3,
         out_specs=[spec, spec],
         out_shape=[shape, shape],
+        name="fedagg_norms",
         interpret=resolve_interpret(interpret),
     )(shaped(x_t), shaped(x_stale), shaped(delta))
     return jnp.stack([jnp.sum(dist), jnp.sum(dn)])
@@ -196,6 +197,7 @@ def fedagg_axpy(x_t: jax.Array, delta: jax.Array, eta: jax.Array,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
+        name="fedagg_axpy",
         interpret=resolve_interpret(interpret),
     )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(delta))
     return out.reshape(n)
@@ -285,6 +287,7 @@ def fedagg_norms_batched(x_t: jax.Array, x_stales: jax.Array,
         out_specs=specs,
         out_shape=shapes,
         compiler_params=_batched_params(interpret),
+        name="fedagg_norms_batched",
         interpret=interpret,
     )(shaped1(x_t), shapedb(x_stales), shapedb(deltas))
     return _sum_gram(*out)
@@ -332,6 +335,7 @@ def fedagg_apply_batched(x_t: jax.Array, deltas: jax.Array, etas: jax.Array,
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * rows, LANES), x_t.dtype),
         compiler_params=_batched_params(interpret),
+        name="fedagg_apply_batched",
         interpret=interpret,
     )(etas.reshape(1, b).astype(jnp.float32),
       x_t.reshape(g * rows, LANES), deltas.reshape(b, g * rows, LANES))
@@ -382,6 +386,7 @@ def fedagg_fused(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
             jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
             shape, shape,
         ],
+        name="fedagg_fused",
         interpret=resolve_interpret(interpret),
     )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(x_stale),
       shaped(delta))
@@ -462,6 +467,7 @@ def fedagg_norms_q(x_t: jax.Array, x_stale: jax.Array, q: jax.Array,
         ],
         out_specs=[spec, spec],
         out_shape=[shape, shape],
+        name="fedagg_norms_q",
         interpret=resolve_interpret(interpret),
     )(shaped(x_t), shaped(x_stale), shaped(q), _scale_rows(scales, g))
     return jnp.stack([jnp.sum(dist), jnp.sum(dn)])
@@ -496,6 +502,7 @@ def fedagg_axpy_q(x_t: jax.Array, q: jax.Array, scales: jax.Array,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
+        name="fedagg_axpy_q",
         interpret=resolve_interpret(interpret),
     )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(q),
       _scale_rows(scales, g))
@@ -540,6 +547,7 @@ def fedagg_norms_batched_q(x_t: jax.Array, x_stales: jax.Array,
         out_specs=specs,
         out_shape=shapes,
         compiler_params=_batched_params(interpret),
+        name="fedagg_norms_batched_q",
         interpret=interpret,
     )(shaped1(x_t), shapedb(x_stales), shapedb(qs), _scale_rows(scales, g))
     return _sum_gram(*out)
@@ -578,6 +586,7 @@ def fedagg_apply_batched_q(x_t: jax.Array, qs: jax.Array, scales: jax.Array,
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * rows, LANES), x_t.dtype),
         compiler_params=_batched_params(interpret),
+        name="fedagg_apply_batched_q",
         interpret=interpret,
     )(etas.reshape(1, b).astype(jnp.float32),
       x_t.reshape(g * rows, LANES), qs.reshape(b, g * rows, LANES),

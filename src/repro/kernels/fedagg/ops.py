@@ -25,6 +25,7 @@ from repro.core.aggregation import (AggregationResult, gamma_eta_from_sq,
 from repro.kernels.fedagg import fedagg
 from repro.kernels.fedagg.fedagg import BLOCK_ROWS, LANES
 from repro.utils import pytree as pt
+from repro.utils import trace
 
 PyTree = Any
 _BLOCK = BLOCK_ROWS * LANES
@@ -79,8 +80,28 @@ def flat_aggregate_displacement(x_t: jax.Array, disp: jax.Array,
 
 _norms_batched = jax.jit(fedagg.fedagg_norms_batched,
                          static_argnames=("interpret",))
+
 _apply_batched = jax.jit(fedagg.fedagg_apply_batched,
                          static_argnames=("interpret",))
+
+
+def _host_schedule(d0, dn_sq, cross, gram, *, lam: float, eps: float,
+                   cap: float, screen):
+    """Read the Gram sweep's outputs to the host and resolve the
+    sequential-equivalence schedule there (screening folded in). Returns
+    (etas, gammas, dists, dnorms, scales)."""
+    with trace.span("server.sync", reads=4):
+        d0, dn_sq, cross, gram = (np.asarray(a)
+                                  for a in (d0, dn_sq, cross, gram))
+    scales = None
+    if screen is not None:
+        dns = np.sqrt(np.maximum(np.asarray(dn_sq, np.float64), 0.0))
+        scales = screen(dns.astype(np.float32))
+    with trace.span("server.schedule"):
+        etas, gammas, dists, dnorms = sequential_batch_schedule(
+            d0, dn_sq, cross, gram, lam=lam, eps=eps, cap=cap,
+            scales=scales)
+    return etas, gammas, dists, dnorms, scales
 
 
 def flat_aggregate_batched(x_t: jax.Array, x_stales: jax.Array,
@@ -104,14 +125,9 @@ def flat_aggregate_batched(x_t: jax.Array, x_stales: jax.Array,
     defense reuses the batched Gram sweep: no extra pass over the
     parameter vector happens. ``scales`` is None when ``screen`` is.
     """
-    d0, dn_sq, cross, gram = _norms_batched(x_t, x_stales, deltas,
-                                            interpret=interpret)
-    scales = None
-    if screen is not None:
-        dns = np.sqrt(np.maximum(np.asarray(dn_sq, np.float64), 0.0))
-        scales = screen(dns.astype(np.float32))
-    etas, gammas, dists, dnorms = sequential_batch_schedule(
-        d0, dn_sq, cross, gram, lam=lam, eps=eps, cap=cap, scales=scales)
+    etas, gammas, dists, dnorms, scales = _host_schedule(
+        *_norms_batched(x_t, x_stales, deltas, interpret=interpret),
+        lam=lam, eps=eps, cap=cap, screen=screen)
     new = _apply_batched(x_t, deltas, jnp.asarray(etas),
                          interpret=interpret)
     return new, etas, gammas, dists, dnorms, scales
@@ -164,14 +180,9 @@ def flat_aggregate_batched_q(x_t: jax.Array, x_stales: jax.Array,
     The screening decider sees the kernel-emitted DEQUANTIZED norms, and
     clip scales fold into the eta schedule exactly (int8 clip-by-scales is
     exact). Same return signature as the uncompressed path."""
-    d0, dn_sq, cross, gram = _norms_batched_q(x_t, x_stales, qs, qscales,
-                                              interpret=interpret)
-    scales = None
-    if screen is not None:
-        dns = np.sqrt(np.maximum(np.asarray(dn_sq, np.float64), 0.0))
-        scales = screen(dns.astype(np.float32))
-    etas, gammas, dists, dnorms = sequential_batch_schedule(
-        d0, dn_sq, cross, gram, lam=lam, eps=eps, cap=cap, scales=scales)
+    etas, gammas, dists, dnorms, scales = _host_schedule(
+        *_norms_batched_q(x_t, x_stales, qs, qscales, interpret=interpret),
+        lam=lam, eps=eps, cap=cap, screen=screen)
     new = _apply_batched_q(x_t, qs, qscales, jnp.asarray(etas),
                            interpret=interpret)
     return new, etas, gammas, dists, dnorms, scales

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils import trace
+
 PyTree = Any
 
 
@@ -178,7 +180,8 @@ class FlatParams:
     @property
     def tree(self) -> PyTree:
         if self._tree_cache is None:
-            self._tree_cache = self.spec.unflatten(self.vec)
+            with trace.span("server.unflatten"):
+                self._tree_cache = self.spec.unflatten(self.vec)
         return self._tree_cache
 
     def replace(self, vec: jax.Array) -> "FlatParams":
