@@ -75,6 +75,24 @@ class UpdateRecord:
     screen: str = "accept"
 
 
+def _flatten_stats(upds: List[ClientUpdate]) -> dict:
+    """Stats of a ``server.flatten`` span, read when it closes: ``staging``
+    is "host" when deltas with NumPy leaves were joined on the host, any
+    device delta among them read back first, and uploaded once
+    (``pt.on_host``), and "device" when the staging program took the
+    leaves as they were; ``h2d_bytes`` and ``d2h_bytes`` count the deltas'
+    bytes that crossed each way."""
+    deltas = [u.delta for u in upds]
+
+    def back():
+        if not pt.on_host(deltas):
+            return 0
+        return trace.nbytes(deltas) - trace.host_nbytes(deltas)
+    return {"staging": lambda: "host" if pt.on_host(deltas) else "device",
+            "h2d_bytes": lambda: trace.host_nbytes(deltas) + back(),
+            "d2h_bytes": back}
+
+
 class AsyncServer:
     """Base class for asynchronous servers (one aggregation per arrival)."""
 
@@ -397,8 +415,7 @@ class AsyncFedEDServer(AsyncServer):
             return gamma, eta, dist, dnorm, d
         # bf16 payloads ride the f32 kernels unchanged (tiles upcast on
         # load, f32 accumulation), so only the operand swaps
-        with trace.span("server.flatten",
-                        h2d_bytes=lambda: trace.host_nbytes(upd.delta)):
+        with trace.span("server.flatten", **_flatten_stats([upd])):
             d = (self._wire_padded(cd)[0] if cd is not None
                  else self._flat.spec.flatten(upd.delta))
         if self.gmis_mode == "displacement":
@@ -518,18 +535,18 @@ class AsyncFedEDServer(AsyncServer):
         screen_fn = (None if self.screen is None else
                      lambda dns: self.screen.decide_batch(
                          dns, [u.client_id for u in upds]))
-        with trace.span("server.flatten",
-                        h2d_bytes=lambda: trace.host_nbytes(
-                            [u.delta for u in upds])):
-            stales = jnp.stack([self.gmis.get(u.snapshot_iter)[0]
-                                for u in upds])
-            if mode != "int8":
-                # "off" flattens pytrees; "bf16" stacks the bf16 payloads
-                # straight through the f32 kernels (tiles upcast on load)
-                deltas = jnp.stack([self._wire_padded(u.delta)[0]
-                                    if mode == "bf16"
-                                    else spec.flatten(u.delta)
-                                    for u in upds])
+        with trace.span("server.flatten", **_flatten_stats(upds)):
+            stales = [self.gmis.get(u.snapshot_iter)[0] for u in upds]
+            # "off" flattens pytrees; "bf16" stacks the bf16 payloads
+            # straight through the f32 kernels (tiles upcast on load)
+            if mode == "off":
+                stales, deltas = spec.stack(stales, [u.delta for u in upds])
+            elif mode == "bf16":
+                stales, deltas = pt.stack_rows(
+                    stales, [self._wire_padded(u.delta)[0] for u in upds],
+                    spec.n_padded)
+            else:
+                stales = jnp.stack(stales)
         if mode == "int8":
             wires = [self._wire_padded(u.delta) for u in upds]
             qs = jnp.stack([q for q, _ in wires])

@@ -111,15 +111,83 @@ def tree_unflatten_from_vector(vec: jax.Array, like: PyTree) -> PyTree:
     return jax.tree.unflatten(treedef, out)
 
 
+def on_host(tree: PyTree) -> bool:
+    """True when any leaf of ``tree`` is a NumPy array: staging then joins
+    the leaves on the host, reading any device leaf back first, and
+    uploads the result once."""
+    return any(isinstance(l, np.ndarray) for l in jax.tree.leaves(tree))
+
+
+def _host_join(leaves, out: np.ndarray) -> np.ndarray:
+    """Write the raveled leaves into ``out`` back to back (cast to its
+    dtype on assignment)."""
+    off = 0
+    for l in leaves:
+        out[off:off + l.size] = l.reshape(-1)
+        off += l.size
+    return out
+
+
+# The staging programs below are module-level jits, so they are cached by
+# layout (leaf shapes and dtypes, the padded length, B) and not by FlatSpec
+# or server instance: a layout compiled once, by any instance, runs again
+# with no compile. NumPy and uncommitted device arguments of one shape share
+# an executable, but each new mix of them is traced again: so each program
+# is called with one kind of argument per position.
+
+def _zeros_after(rows, n_padded: int) -> jax.Array:
+    """The zero padding that takes the last axis of ``rows`` (a list of
+    arrays that concatenate along it) to ``n_padded``. Concatenated with
+    them, it fills one output buffer; ``jnp.pad`` of their concatenation
+    would stage it in a temporary of the same size first."""
+    n = sum(r.shape[-1] for r in rows)
+    return jnp.zeros((*rows[0].shape[:-1], n_padded - n), rows[0].dtype)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _flatten(parts, n_padded: int) -> jax.Array:
+    """Leaves (or one host-joined vector) -> padded flat f32 vector."""
+    flat = [jnp.ravel(p).astype(jnp.float32) for p in parts]
+    return jnp.concatenate(flat + [_zeros_after(flat, n_padded)])
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _unflatten(vec: jax.Array, shapes, dtypes) -> list:
+    """Padded flat vector -> leaves: static slices, reshapes and casts."""
+    out, off = [], 0
+    for shape, dtype in zip(shapes, dtypes):
+        size = int(np.prod(shape))
+        out.append(jnp.reshape(vec[off:off + size], shape).astype(dtype))
+        off += size
+    return out
+
+
+@jax.jit
+def _join_rows(rows) -> jax.Array:
+    """B lists of leaves -> (B, n) f32, each row its leaves joined."""
+    return jnp.stack([jnp.concatenate([jnp.ravel(l).astype(jnp.float32)
+                                       for l in row]) for row in rows])
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def stack_rows(stales, rows, n_padded: int):
+    """A list of ``B`` padded flat vectors and ``B`` delta rows -> two
+    ``(B, n_padded)`` arrays in one compiled program. ``rows`` is a ``(B,
+    n)`` array or a list of ``B`` vectors; it keeps its dtype and is
+    zero-padded on the right."""
+    d = jnp.stack(rows) if isinstance(rows, list) else rows
+    return jnp.stack(stales), jnp.concatenate(
+        [d, _zeros_after([d], n_padded)], axis=1)
+
+
 class FlatSpec:
     """Cached flatten/unflatten spec for a fixed pytree structure.
 
     Flattening a pytree for the fedagg kernels means: ravel every leaf to
     f32, concatenate, and zero-pad to a multiple of ``block`` (the kernel's
-    VMEM tile). Doing that naively per server step re-walks the tree and
-    re-computes shapes/offsets each time; ``FlatSpec`` captures the treedef,
-    leaf shapes/dtypes and the padded length once so both directions are a
-    single concat/split with no Python re-derivation.
+    VMEM tile). ``FlatSpec`` captures the treedef, leaf shapes/dtypes and
+    the padded length once; each direction is then one compiled program
+    (``_flatten``, ``_unflatten``), with the treedef applied on the host.
     """
 
     __slots__ = ("treedef", "shapes", "dtypes", "sizes", "n", "n_padded",
@@ -135,19 +203,38 @@ class FlatSpec:
         self.n_padded = self.n + (-self.n) % max(self.block, 1)
 
     def flatten(self, tree: PyTree) -> jax.Array:
-        """Pytree (matching this spec) -> padded flat f32 vector."""
-        vec = tree_flatten_to_vector(tree)
-        if self.n_padded != self.n:
-            vec = jnp.pad(vec, (0, self.n_padded - self.n))
-        return vec
+        """Pytree (matching this spec) -> padded flat f32 vector. NumPy
+        leaves are joined on the host and uploaded once, unpadded (see
+        :func:`on_host`); device leaves go to the program as they are."""
+        leaves = jax.tree.leaves(tree)
+        if on_host(leaves):
+            leaves = [_host_join(jax.device_get(leaves),
+                                 np.empty((self.n,), np.float32))]
+        return _flatten(leaves, self.n_padded)
+
+    def stack(self, stales, trees):
+        """``B`` padded flat vectors and ``B`` pytrees matching this spec ->
+        ``(B, n_padded)`` stacks of the vectors and of the flattened trees
+        (see :func:`stack_rows`). Trees holding any NumPy leaf are
+        staged on the host (:func:`on_host`): all ``B`` are joined into
+        one ``(B, n)`` array, any device leaf read back first in one call,
+        and uploaded once. Device trees are joined on the device. Either
+        way the stacking program of each ``B`` takes the same device
+        arguments, so one trace of it serves every source of deltas."""
+        leaves = [jax.tree.leaves(t) for t in trees]
+        if on_host(leaves):
+            rows = np.empty((len(leaves), self.n), np.float32)
+            for row, l in zip(rows, jax.device_get(leaves)):
+                _host_join(l, row)
+            rows = jax.device_put(rows)
+        else:
+            rows = _join_rows(leaves)
+        return stack_rows(list(stales), rows, self.n_padded)
 
     def unflatten(self, vec: jax.Array) -> PyTree:
         """Padded flat vector -> pytree with the original shapes/dtypes."""
-        out, off = [], 0
-        for shape, dtype, size in zip(self.shapes, self.dtypes, self.sizes):
-            out.append(jnp.reshape(vec[off:off + size], shape).astype(dtype))
-            off += size
-        return jax.tree.unflatten(self.treedef, out)
+        return jax.tree.unflatten(
+            self.treedef, _unflatten(vec, self.shapes, self.dtypes))
 
     def zeros(self) -> jax.Array:
         return jnp.zeros((self.n_padded,), jnp.float32)

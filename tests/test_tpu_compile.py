@@ -1,5 +1,6 @@
 """Compile guard: every fedagg kernel, and every model-sharded body, must
-compile for a TPU v5e chip that is described, not attached.
+compile for a TPU v5e chip that is described, not attached; the server's
+staging programs must fit beside the model there.
 
 Interpret-mode parity tests (test_kernels.py, test_compression.py) cannot
 see Mosaic's block-shape and VMEM rules; these compiles can. Sizes are
@@ -22,6 +23,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from repro.kernels.fedagg import fedagg, sharded
+from repro.utils import pytree as pt
 from repro.sharding.specs import (FLAT_SCALES_SPEC, FLAT_STACKED_SCALES_SPEC,
                                   FLAT_STACKED_SPEC, FLAT_VEC_SPEC)
 
@@ -110,6 +112,33 @@ class TestSingleChip:
                 *a, interpret=False), xt, stales, deltas)
             _compile(lambda *a: fedagg.fedagg_apply_batched(
                 *a, interpret=False), xt, deltas, etas)
+
+    @pytest.mark.parametrize("direction", ["flatten", "unflatten"])
+    def test_staging_keeps_no_model_sized_temporary(self, one_chip,
+                                                    direction):
+        """The server's flat<->pytree staging at the phase-2 leaf shapes
+        writes its result in place: no temporary as large as the flat
+        vector beside it (the chip runs near full HBM)."""
+        shapes = _danube_2l_leaf_shapes()
+        n = sum(math.prod(s) for s in shapes)
+        assert n == DANUBE_2L_PARAMS
+        if direction == "flatten":
+            compiled = pt._flatten.lower(
+                [_sds(one_chip, s) for s in shapes], N_PHASE2).compile()
+        else:
+            compiled = pt._unflatten.lower(
+                _sds(one_chip, (N_PHASE2,)), tuple(shapes),
+                (np.dtype(np.float32),) * len(shapes)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < n * 4 // 2, mem
+
+
+def _danube_2l_leaf_shapes():
+    """h2o-danube-1.8b's weight shapes at its published widths, 2 layers."""
+    d, kv, ff, vocab = 2560, 640, 6912, 32000
+    layer = [(d, d), (d, kv), (d, kv), (d, d), (d, ff), (d, ff), (ff, d),
+             (d,), (d,)]
+    return [(vocab, d), *layer, *layer, (d,), (d, vocab)]
 
 
 @pytest.fixture

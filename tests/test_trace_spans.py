@@ -128,6 +128,15 @@ def test_server_flatten_counts_host_deltas_it_uploads(loop_run, cohort_run):
             assert flat[3]["h2d_bytes"] == d[3]["B"] * n * 4
 
 
+def test_server_flatten_says_where_it_staged(loop_run, cohort_run):
+    # loop-engine deltas go to the staging program as device leaves; the
+    # cohort's host deltas are joined on the host and uploaded once
+    assert {s[3]["staging"] for s in _named(loop_run[2],
+                                            "server.flatten")} == {"device"}
+    assert {s[3]["staging"] for s in _named(cohort_run[2],
+                                            "server.flatten")} == {"host"}
+
+
 def test_cohort_fanout_stats_match_the_arrays_moved(cohort_run):
     sim, _, spans = cohort_run
     n = pt.tree_size(sim.server.params)
