@@ -171,9 +171,9 @@ def phase_arch(dev) -> None:
         f"GiB beside one client step")
     sim, res, secs = arch_run(task, fed)
     vec = sim.server._flat.vec
-    text = ops.flat_aggregate.lower(
-        vec, vec, vec, lam=fed.lam, eps=fed.eps,
-        cap=fed.staleness_cap).compile().as_text()
+    text = ops.flat_aggregate_program(True).lower(
+        vec, vec, vec, lam=fed.lam, eps=fed.eps, cap=fed.staleness_cap,
+        interpret=None).compile().as_text()
     if "tpu_custom_call" not in text:
         raise AssertionError("flat_aggregate compiled without a Mosaic "
                              "kernel (no tpu_custom_call)")

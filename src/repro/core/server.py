@@ -418,20 +418,29 @@ class AsyncFedEDServer(AsyncServer):
         with trace.span("server.flatten", **_flatten_stats([upd])):
             d = (self._wire_padded(cd)[0] if cd is not None
                  else self._flat.spec.flatten(upd.delta))
-        if self.gmis_mode == "displacement":
-            new_vec, gamma, eta, dist, dnorm = (
-                self._agg["flat_aggregate_displacement"](
-                    self._flat.vec, self.gmis.displacement(upd.client_id),
-                    d, self._zeros_vec(), lam=fed.lam, eps=fed.eps,
-                    cap=fed.staleness_cap, interpret=self._interpret))
-            self.gmis.release(upd.client_id)
-        else:
-            stale, _ = self.gmis.get(upd.snapshot_iter)
-            with trace.span("server.kernels"):
+        # the AXPY writes x_{t+1} into d where the drain made d and reads
+        # it no more: an f32 flatten (not the wire payload, nor a leaf of
+        # the caller's that the flatten handed back as it was) on the
+        # ring, whose on_aggregate ignores the delta
+        in_place = (self.gmis_mode == "ring" and self._shards == 1
+                    and cd is None and d.dtype == self._flat.vec.dtype
+                    and all(d is not l for l in jax.tree.leaves(upd.delta)))
+        with trace.span("server.kernels", in_place=int(in_place)):
+            if self.gmis_mode == "displacement":
+                new_vec, gamma, eta, dist, dnorm = (
+                    self._agg["flat_aggregate_displacement"](
+                        self._flat.vec,
+                        self.gmis.displacement(upd.client_id), d,
+                        self._zeros_vec(), lam=fed.lam, eps=fed.eps,
+                        cap=fed.staleness_cap, interpret=self._interpret))
+                self.gmis.release(upd.client_id)
+            else:
+                stale, _ = self.gmis.get(upd.snapshot_iter)
                 new_vec, gamma, eta, dist, dnorm = (
                     self._agg["flat_aggregate"](
                         self._flat.vec, stale, d, lam=fed.lam, eps=fed.eps,
-                        cap=fed.staleness_cap, interpret=self._interpret))
+                        cap=fed.staleness_cap, interpret=self._interpret,
+                        in_place=in_place))
         self._flat = self._flat.replace(new_vec)
         return gamma, eta, dist, dnorm, d
 
@@ -557,7 +566,7 @@ class AsyncFedEDServer(AsyncServer):
                     eps=fed.eps, cap=fed.staleness_cap,
                     interpret=self._interpret, screen=screen_fn))
         else:
-            with trace.span("server.kernels"):
+            with trace.span("server.kernels", in_place=0):
                 new_vec, etas, gammas, dists, dnorms, scales = (
                     self._agg["flat_aggregate_batched"](
                         self._flat.vec, stales, deltas, lam=fed.lam,

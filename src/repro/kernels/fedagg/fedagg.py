@@ -180,11 +180,18 @@ def _axpy_kernel(eta_ref, xt_ref, d_ref, out_ref):
 
 
 def fedagg_axpy(x_t: jax.Array, delta: jax.Array, eta: jax.Array,
-                *, interpret: Optional[bool] = None) -> jax.Array:
-    """x_t + eta * delta, flat (n,) blocked through VMEM. eta: scalar."""
+                *, interpret: Optional[bool] = None,
+                in_place: bool = False) -> jax.Array:
+    """x_t + eta * delta, flat (n,) blocked through VMEM. eta: scalar.
+
+    ``in_place`` writes the result into ``delta``'s buffer (same dtype as
+    ``x_t``): each grid step reads and writes the same tile. It saves the
+    output's allocation only where the caller's jit donates ``delta``;
+    without donation XLA copies ``delta`` first."""
     n = x_t.shape[0]
     block = BLOCK_ROWS * LANES
     assert n % block == 0, (n, block)
+    assert not in_place or delta.dtype == x_t.dtype, (delta.dtype, x_t.dtype)
     g = n // block
     shaped = lambda a: a.reshape(g * BLOCK_ROWS, LANES)
     out = pl.pallas_call(
@@ -197,6 +204,7 @@ def fedagg_axpy(x_t: jax.Array, delta: jax.Array, eta: jax.Array,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
+        input_output_aliases={2: 0} if in_place else {},
         name="fedagg_axpy",
         interpret=resolve_interpret(interpret),
     )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(delta))

@@ -50,16 +50,36 @@ def _pad_flat(tree: PyTree) -> jax.Array:
 # default everywhere) lets the kernels pick their mode from the platform
 # at trace time (``fedagg.resolve_interpret``).
 
-@functools.partial(jax.jit, static_argnames=("lam", "eps", "cap", "interpret"))
+@functools.lru_cache(maxsize=None)
+def flat_aggregate_program(in_place: bool):
+    """The jitted program behind :func:`flat_aggregate`, one per
+    ``in_place``. In place, the jit donates ``delta`` and the AXPY writes
+    x_{t+1} into its buffer, so the step allocates no model-sized output;
+    the norms sweep has read ``delta`` by then, since the AXPY needs eta."""
+
+    def flat_aggregate(x_t, x_stale, delta, *, lam, eps, cap, interpret):
+        sq = fedagg.fedagg_norms(x_t, x_stale, delta, interpret=interpret)
+        gamma, eta, dist, dnorm = gamma_eta_from_sq(sq[0], sq[1], lam, eps,
+                                                    cap)
+        new = fedagg.fedagg_axpy(x_t, delta, eta, interpret=interpret,
+                                 in_place=in_place)
+        return new, gamma, eta, dist, dnorm
+
+    return jax.jit(flat_aggregate,
+                   static_argnames=("lam", "eps", "cap", "interpret"),
+                   donate_argnames=("delta",) if in_place else ())
+
+
 def flat_aggregate(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array, *,
                    lam: float, eps: float, cap: float = 0.0,
-                   interpret: Optional[bool] = None):
+                   interpret: Optional[bool] = None, in_place: bool = False):
     """One Eq.(5-7) step on padded flat vectors: a norms sweep, scalar
-    gamma/eta, an AXPY sweep. Returns (new_vec, gamma, eta, dist, dnorm)."""
-    sq = fedagg.fedagg_norms(x_t, x_stale, delta, interpret=interpret)
-    gamma, eta, dist, dnorm = gamma_eta_from_sq(sq[0], sq[1], lam, eps, cap)
-    new = fedagg.fedagg_axpy(x_t, delta, eta, interpret=interpret)
-    return new, gamma, eta, dist, dnorm
+    gamma/eta, an AXPY sweep. Returns (new_vec, gamma, eta, dist, dnorm).
+    With ``in_place`` (an f32 ``delta`` the caller owns and reads no
+    more) ``new_vec`` takes over ``delta``'s buffer and ``delta`` is
+    deleted; otherwise every argument stays valid."""
+    return flat_aggregate_program(bool(in_place))(
+        x_t, x_stale, delta, lam=lam, eps=eps, cap=cap, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("lam", "eps", "cap", "interpret"))

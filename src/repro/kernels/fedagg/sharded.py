@@ -91,10 +91,12 @@ def _aggregate(shards, lam, eps, cap, interpret):
 
 
 def flat_aggregate(x_t, x_stale, delta, *, lam, eps, cap=0.0, shards,
-                   interpret=None):
+                   interpret=None, in_place=False):
     """Sharded twin of ``ops.flat_aggregate``: one Eq.(5-7) dispatch, one
     cross-shard psum. Returns (new_vec [model-sharded], gamma, eta, dist,
-    dnorm)."""
+    dnorm). It always writes a fresh vector: ``in_place`` must be False."""
+    if in_place:
+        raise ValueError("the sharded aggregation does not write in place")
     return _aggregate(int(shards), float(lam), float(eps), float(cap),
                       _mode(interpret))(x_t, x_stale, delta)
 

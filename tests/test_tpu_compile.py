@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
-from repro.kernels.fedagg import fedagg, sharded
+from repro.kernels.fedagg import fedagg, ops, sharded
 from repro.utils import pytree as pt
 from repro.sharding.specs import (FLAT_SCALES_SPEC, FLAT_STACKED_SCALES_SPEC,
                                   FLAT_STACKED_SPEC, FLAT_VEC_SPEC)
@@ -131,6 +131,18 @@ class TestSingleChip:
                 (np.dtype(np.float32),) * len(shapes)).compile()
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes < n * 4 // 2, mem
+
+    def test_in_place_aggregate_writes_into_the_delta(self, one_chip):
+        """The ring drain's step at the phase-2 length: x_{t+1} takes the
+        donated delta's buffer, with no model-sized temporary beside it."""
+        vec = _sds(one_chip, (N_PHASE2,))
+        compiled = ops.flat_aggregate_program(True).lower(
+            vec, vec, vec, lam=1.0, eps=1.0, cap=0.0,
+            interpret=False).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= N_PHASE2 * 4, mem
+        assert mem.temp_size_in_bytes < N_PHASE2 * 4 // 2, mem
 
 
 def _danube_2l_leaf_shapes():
