@@ -166,8 +166,8 @@ def run_quant(batch: int = 8, n_leaves: int = 20, leaf: int = 50_000
     xs = xt + 0.01 * jax.random.normal(jax.random.fold_in(key, 1), (n,))
 
     def fused_q(x, stale, q, scales):
-        return fedagg_ops.flat_aggregate_q(x, stale, q, scales,
-                                           lam=1.0, eps=1.0)[0]
+        return fedagg_ops.flat_aggregate(x, stale, q, scales=scales,
+                                         lam=1.0, eps=1.0)[0]
 
     @jax.jit
     def dequant_then_f32(x, stale, q, scales):

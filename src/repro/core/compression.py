@@ -14,8 +14,7 @@ bounded instead of accumulating across rounds.
 generic ``pt.tree_*`` helpers must fail loudly on a compressed delta
 rather than silently treating ``q`` as parameters. Servers decompress
 explicitly (pytree backends) or hand ``q``/``scales`` straight to the
-quant-fused Pallas kernels (``fedagg_norms_q`` et al.), which dequantize
-one VMEM tile at a time.
+fedagg sweeps (``scales=``), which dequantize one VMEM tile at a time.
 """
 from __future__ import annotations
 

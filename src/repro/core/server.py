@@ -32,12 +32,11 @@ from repro.utils import trace
 
 PyTree = Any
 
-#: flat-kernel entry points mirrored by the model-sharded twins
-#: (`kernels.fedagg.sharded`): the server binds one set per instance so
-#: `_aggregate_flat`/`on_update_batch` never branch on the shard count.
-_AGG_OPS = ("flat_aggregate", "flat_aggregate_displacement",
-            "flat_aggregate_q", "flat_aggregate_displacement_q",
-            "flat_aggregate_batched", "flat_aggregate_batched_q")
+#: the flat entry points, replicated (`kernels.fedagg.ops`) or
+#: model-sharded (`kernels.fedagg.sharded`): the server binds one set per
+#: instance so `_aggregate_flat`/`_drain_batched` never branch on the
+#: shard count.
+_AGG_OPS = ("flat_aggregate", "flat_aggregate_batched")
 
 
 @dataclasses.dataclass
@@ -210,26 +209,23 @@ class AsyncFedEDServer(AsyncServer):
       norms sweep and an AXPY sweep (DESIGN.md §4). Bursts drained via
       :meth:`on_update_batch` go through the multi-delta batched kernel.
       The kernels run compiled on TPU and interpreted on CPU
-      (``fedagg.resolve_interpret``); ``interpret`` overrides that only
-      when given.
+      (``fedagg.resolve_interpret``).
     """
 
     name = "asyncfeded"
 
     def __init__(self, params: PyTree, fed: FedConfig,
                  gmis_mode: str = "ring", per_leaf: bool = False,
-                 backend: str = "pytree",
-                 interpret: Optional[bool] = None):
+                 backend: str = "pytree"):
         if backend not in ("pytree", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
         if backend == "pallas" and per_leaf:
             raise ValueError("per-leaf staleness needs the pytree backend")
         self.backend = backend
-        self._interpret = interpret
         # model-axis sharding (DESIGN.md §14): >1 places the flat global
         # vector (and, via the GMIS pass-through, every snapshot) over
         # the `model` mesh axis and routes aggregation through the
-        # sharded kernel twins — one cross-shard psum per Eq. 6 norm.
+        # sharded entry points — one cross-shard psum per Eq. 6 norm.
         self._shards = fed.model_shards if backend == "pallas" else 1
         if self._shards > 1:
             from repro.kernels.fedagg import sharded as _sharded
@@ -387,37 +383,12 @@ class AsyncFedEDServer(AsyncServer):
     def _aggregate_flat(self, upd: ClientUpdate):
         fed = self.fed
         cd = upd.delta if compression.is_compressed(upd.delta) else None
-        if cd is not None and cd.mode == "int8":
-            # quant-fused path: q/scales go straight into the kernels,
-            # dequantized one VMEM tile at a time (DESIGN.md §13)
-            q, qscales = self._wire_padded(cd)
-            if self.gmis_mode == "displacement":
-                new_vec, gamma, eta, dist, dnorm = (
-                    self._agg["flat_aggregate_displacement_q"](
-                        self._flat.vec,
-                        self.gmis.displacement(upd.client_id), q,
-                        qscales, self._zeros_vec(), lam=fed.lam, eps=fed.eps,
-                        cap=fed.staleness_cap, interpret=self._interpret))
-                self.gmis.release(upd.client_id)
-            else:
-                stale, _ = self.gmis.get(upd.snapshot_iter)
-                new_vec, gamma, eta, dist, dnorm = (
-                    self._agg["flat_aggregate_q"](
-                        self._flat.vec, stale, q, qscales, lam=fed.lam,
-                        eps=fed.eps, cap=fed.staleness_cap,
-                        interpret=self._interpret))
-            self._flat = self._flat.replace(new_vec)
-            # ring-GMIS on_aggregate is a no-op, so the f32 delta is only
-            # materialized when displacement accumulators need it
-            d = (compression.dequantize(
-                    dataclasses.replace(cd, q=q, scales=qscales))
-                 if self.gmis_mode == "displacement" else cd)
-            return gamma, eta, dist, dnorm, d
-        # bf16 payloads ride the f32 kernels unchanged (tiles upcast on
-        # load, f32 accumulation), so only the operand swaps
+        # compressed payloads go to the kernels as they came (padded):
+        # bf16 tiles upcast on load, int8 tiles dequantize with their
+        # block scales (DESIGN.md §13)
         with trace.span("server.flatten", **_flatten_stats([upd])):
-            d = (self._wire_padded(cd)[0] if cd is not None
-                 else self._flat.spec.flatten(upd.delta))
+            d, scales = (self._wire_padded(cd) if cd is not None
+                         else (self._flat.spec.flatten(upd.delta), None))
         # the AXPY writes x_{t+1} into d where the drain made d and reads
         # it no more: an f32 flatten (not the wire payload, nor a leaf of
         # the caller's that the flatten handed back as it was) on the
@@ -425,22 +396,23 @@ class AsyncFedEDServer(AsyncServer):
         in_place = (self.gmis_mode == "ring" and self._shards == 1
                     and cd is None and d.dtype == self._flat.vec.dtype
                     and all(d is not l for l in jax.tree.leaves(upd.delta)))
+        displaced = self.gmis_mode == "displacement"
         with trace.span("server.kernels", in_place=int(in_place)):
-            if self.gmis_mode == "displacement":
-                new_vec, gamma, eta, dist, dnorm = (
-                    self._agg["flat_aggregate_displacement"](
-                        self._flat.vec,
-                        self.gmis.displacement(upd.client_id), d,
-                        self._zeros_vec(), lam=fed.lam, eps=fed.eps,
-                        cap=fed.staleness_cap, interpret=self._interpret))
-                self.gmis.release(upd.client_id)
-            else:
-                stale, _ = self.gmis.get(upd.snapshot_iter)
-                new_vec, gamma, eta, dist, dnorm = (
-                    self._agg["flat_aggregate"](
-                        self._flat.vec, stale, d, lam=fed.lam, eps=fed.eps,
-                        cap=fed.staleness_cap, interpret=self._interpret,
-                        in_place=in_place))
+            # displacement: Eq. 6 reads the maintained drift x_t - x_tau
+            # against zeros, and the stale model is never materialized
+            stale, drift = (
+                (self._zeros_vec(), self.gmis.displacement(upd.client_id))
+                if displaced else (self.gmis.get(upd.snapshot_iter)[0], None))
+            new_vec, gamma, eta, dist, dnorm = self._agg["flat_aggregate"](
+                self._flat.vec, stale, d, scales=scales, drift=drift,
+                lam=fed.lam, eps=fed.eps, cap=fed.staleness_cap,
+                in_place=in_place)
+        if displaced:
+            self.gmis.release(upd.client_id)
+            if scales is not None:
+                # the accumulators fold in the delta the AXPY applied
+                d = compression.dequantize(
+                    dataclasses.replace(cd, q=d, scales=scales))
         self._flat = self._flat.replace(new_vec)
         return gamma, eta, dist, dnorm, d
 
@@ -546,8 +518,9 @@ class AsyncFedEDServer(AsyncServer):
                          dns, [u.client_id for u in upds]))
         with trace.span("server.flatten", **_flatten_stats(upds)):
             stales = [self.gmis.get(u.snapshot_iter)[0] for u in upds]
-            # "off" flattens pytrees; "bf16" stacks the bf16 payloads
-            # straight through the f32 kernels (tiles upcast on load)
+            scales = None
+            # "off" flattens pytrees; "bf16" and "int8" stack the padded
+            # payloads, which the kernels decode tile by tile
             if mode == "off":
                 stales, deltas = spec.stack(stales, [u.delta for u in upds])
             elif mode == "bf16":
@@ -555,29 +528,22 @@ class AsyncFedEDServer(AsyncServer):
                     stales, [self._wire_padded(u.delta)[0] for u in upds],
                     spec.n_padded)
             else:
+                wires = [self._wire_padded(u.delta) for u in upds]
                 stales = jnp.stack(stales)
-        if mode == "int8":
-            wires = [self._wire_padded(u.delta) for u in upds]
-            qs = jnp.stack([q for q, _ in wires])
-            qscales = jnp.stack([s for _, s in wires])
-            new_vec, etas, gammas, dists, dnorms, scales = (
-                self._agg["flat_aggregate_batched_q"](
-                    self._flat.vec, stales, qs, qscales, lam=fed.lam,
-                    eps=fed.eps, cap=fed.staleness_cap,
-                    interpret=self._interpret, screen=screen_fn))
-        else:
-            with trace.span("server.kernels", in_place=0):
-                new_vec, etas, gammas, dists, dnorms, scales = (
-                    self._agg["flat_aggregate_batched"](
-                        self._flat.vec, stales, deltas, lam=fed.lam,
-                        eps=fed.eps, cap=fed.staleness_cap,
-                        interpret=self._interpret, screen=screen_fn))
+                deltas = jnp.stack([q for q, _ in wires])
+                scales = jnp.stack([s for _, s in wires])
+        with trace.span("server.kernels", in_place=0):
+            new_vec, etas, gammas, dists, dnorms, keep = (
+                self._agg["flat_aggregate_batched"](
+                    self._flat.vec, stales, deltas, scales=scales,
+                    lam=fed.lam, eps=fed.eps, cap=fed.staleness_cap,
+                    screen=screen_fn))
         self._flat = self._flat.replace(new_vec)
         k_nexts = []
         with trace.span("server.book"):
             for i, upd in enumerate(upds):
-                verdict = ("accept" if scales is None
-                           else screening.verdict_of_scale(float(scales[i])))
+                verdict = ("accept" if keep is None
+                           else screening.verdict_of_scale(float(keep[i])))
                 # pre-increment staleness tau, exactly as in on_update:
                 # the server state at this update's turn in the
                 # sequential equivalence, before its own increment
@@ -787,7 +753,7 @@ class SyncServer:
 def make_server(name: str, params: PyTree, fed: FedConfig, **kw):
     """Build a server by aggregator name. AsyncFedED variants accept
     ``backend="pytree"|"pallas"`` (flat-state fedagg-kernel runtime, see
-    DESIGN.md §4.1), ``gmis_mode``, and ``interpret`` via ``**kw``."""
+    DESIGN.md §4.1) and ``gmis_mode`` via ``**kw``."""
     name = name.lower()
     if name == "asyncfeded":
         return AsyncFedEDServer(params, fed, **kw)

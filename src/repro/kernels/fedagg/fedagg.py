@@ -18,6 +18,13 @@ sums therefore leave each grid step as one lane-dense (8, 128) tile per
 quantity (or a whole-array-width (B, B) / (B, 1) block in the batched
 sweeps), and the wrapper finishes the reduction.
 
+Payloads: every sweep reads its delta tile through one decoder, chosen at
+trace time. With ``scales=None`` (f32 and bf16 deltas) the tile is upcast
+to f32 on load; with ``scales`` (per-block-scaled int8, DESIGN.md §13)
+the sweep also reads one scale row per grid step and dequantizes the tile
+in VMEM, so the f32 delta never exists in HBM. Such a sweep carries its
+kernel name with a ``_q`` suffix.
+
 Kernel mode follows the platform (:func:`resolve_interpret`): the Pallas
 interpreter on CPU, compiled Mosaic on TPU.
 """
@@ -107,7 +114,7 @@ def batched_b_max(delta_bytes: int = 4) -> int:
     auto-window controller targets this as its free-batch ceiling.
 
     ``delta_bytes`` is the per-element width of the resident delta tiles
-    (4 = f32, 2 = bf16, 1 = int8 via the quantization-fused kernels): a
+    (4 = f32, 2 = bf16, 1 = int8 with its block scales): a
     grid step holds one f32 x_t tile, B f32 stale tiles, and B delta tiles
     at that width, so compressed deltas push the knee out — 15 (f32) ->
     20 (bf16) -> 24 (int8) concurrent arrivals at full tile size.
@@ -143,71 +150,144 @@ def _batched_rows(b: int, n: int, interpret: bool,
     return rows
 
 
-def _norms_kernel(xt_ref, xs_ref, d_ref, dist_ref, dn_ref):
+# --------------------------------------------------------- payload decoder --
+# Compressed delta transport (DESIGN.md §13): int8 deltas come with one
+# f32 scale per QBLOCK elements. A grid step reads its tile's scales as
+# one lane-major row, (1, spb) for a single delta and (B, spb) for a
+# batch, from a (steps, 1 or B, spb) array whose block spans the last
+# two dims.
+
+def _scaled(v: jax.Array, s_row: jax.Array) -> jax.Array:
+    """Multiply each QBLOCK_ROWS-row block of the f32 tile ``v`` (rows,
+    LANES) by its scale; ``s_row`` (1, spb) holds one scale per block.
+    The row turns into a column by a small transpose, never by a reshape
+    that moves lanes into sublanes."""
+    spb = s_row.shape[1]
+    col = jnp.transpose(s_row)[:, :, None]             # (spb, 1, 1)
+    return (v.reshape(spb, QBLOCK_ROWS, LANES) * col).reshape(v.shape)
+
+
+def _decode(d: jax.Array, s: Optional[jax.Array]) -> jax.Array:
+    """The f32 delta tile(s) of a payload tile ``d``: upcast where ``s``
+    is None, dequantized where ``d`` is int8 (rows, LANES) with ``s`` (1,
+    spb), or (B, rows, LANES) with ``s`` (B, spb)."""
+    if s is None:
+        return _f32(d)
+    v = d.astype(jnp.float32)
+    if d.ndim == 2:
+        return _scaled(v, s)
+    return jnp.stack([_scaled(v[i], s[i:i + 1]) for i in range(d.shape[0])])
+
+
+def _payload(refs, n_out: int):
+    """Split a kernel's refs after its delta ref into the tile's scale
+    row (None without scales) and its ``n_out`` output refs."""
+    s = refs[0][0] if len(refs) > n_out else None
+    return s, refs[len(refs) - n_out:]
+
+
+def _with_scales(in_specs, operands, delta, scales, g: int, rows: int):
+    """Append the scale operand after the delta, one row per grid step,
+    where the payload is int8; unchanged otherwise."""
+    if scales is None:
+        return in_specs, operands
+    assert delta.dtype == jnp.int8, delta.dtype
+    assert scales.shape == (*delta.shape[:-1], delta.shape[-1] // QBLOCK), (
+        scales.shape, delta.shape)
+    if scales.ndim == 1:
+        s = scales.reshape(g, 1, -1)
+    else:
+        s = scales.reshape(scales.shape[0], g, -1).transpose(1, 0, 2)
+    spec = pl.BlockSpec((1, s.shape[1], rows // QBLOCK_ROWS),
+                        lambda i: (i, 0, 0))
+    return [*in_specs, spec], [*operands, s]
+
+
+def _name(kernel: str, scales) -> str:
+    return kernel if scales is None else kernel + "_q"
+
+
+def _steps(n: int, rows: int = BLOCK_ROWS) -> int:
+    """Grid steps of ``rows`` rows over a BLOCK-padded flat length."""
+    assert n % (BLOCK_ROWS * LANES) == 0, (n, BLOCK_ROWS * LANES)
+    return n // (rows * LANES)
+
+
+_TILE = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
+
+
+# ------------------------------------------------------------ the sweeps --
+
+def _norms_kernel(xt_ref, xs_ref, d_ref, *refs):
+    s, (dist_ref, dn_ref) = _payload(refs, 2)
     diff = _f32(xt_ref[...]) - _f32(xs_ref[...])
-    d = _f32(d_ref[...])
+    d = _decode(d_ref[...], s)
     dist_ref[...] = _fold(diff * diff)
     dn_ref[...] = _fold(d * d)
 
 
 def fedagg_norms(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
-                 *, interpret: Optional[bool] = None) -> jax.Array:
-    """Inputs: flat (n,) arrays (zero-padded to BLOCK multiple by ops.py).
-    Returns (2,) f32: [||x_t - x_stale||^2, ||delta||^2]."""
+                 scales: Optional[jax.Array] = None, *,
+                 interpret: Optional[bool] = None) -> jax.Array:
+    """Inputs: flat (n,) arrays (zero-padded to BLOCK multiple by ops.py),
+    ``delta`` f32/bf16, or int8 with ``scales`` (n // QBLOCK,).
+    Returns (2,) f32: [||x_t - x_stale||^2, ||delta||^2], the latter of
+    the dequantized delta — exactly what the AXPY applies."""
     n = x_t.shape[0]
-    block = BLOCK_ROWS * LANES
-    assert n % block == 0, (n, block)
-    g = n // block
+    g = _steps(n)
     shaped = lambda a: a.reshape(g * BLOCK_ROWS, LANES)
     spec, shape = _partials(g)
+    in_specs, operands = _with_scales(
+        [_TILE] * 3, [shaped(x_t), shaped(x_stale), shaped(delta)],
+        delta, scales, g, BLOCK_ROWS)
     dist, dn = pl.pallas_call(
         _norms_kernel,
         grid=(g,),
-        in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))] * 3,
+        in_specs=in_specs,
         out_specs=[spec, spec],
         out_shape=[shape, shape],
-        name="fedagg_norms",
+        name=_name("fedagg_norms", scales),
         interpret=resolve_interpret(interpret),
-    )(shaped(x_t), shaped(x_stale), shaped(delta))
+    )(*operands)
     return jnp.stack([jnp.sum(dist), jnp.sum(dn)])
 
 
-def _axpy_kernel(eta_ref, xt_ref, d_ref, out_ref):
+def _axpy_kernel(eta_ref, xt_ref, d_ref, *refs):
+    s, (out_ref,) = _payload(refs, 1)
     eta = eta_ref[0, 0]
     out_ref[...] = (xt_ref[...].astype(jnp.float32)
-                    + eta * d_ref[...].astype(jnp.float32)
-                    ).astype(out_ref.dtype)
+                    + eta * _decode(d_ref[...], s)).astype(out_ref.dtype)
 
 
 def fedagg_axpy(x_t: jax.Array, delta: jax.Array, eta: jax.Array,
-                *, interpret: Optional[bool] = None,
+                scales: Optional[jax.Array] = None, *,
+                interpret: Optional[bool] = None,
                 in_place: bool = False) -> jax.Array:
-    """x_t + eta * delta, flat (n,) blocked through VMEM. eta: scalar.
+    """x_t + eta * delta, flat (n,) blocked through VMEM. eta: scalar;
+    ``delta`` decoded as in :func:`fedagg_norms`.
 
     ``in_place`` writes the result into ``delta``'s buffer (same dtype as
     ``x_t``): each grid step reads and writes the same tile. It saves the
     output's allocation only where the caller's jit donates ``delta``;
     without donation XLA copies ``delta`` first."""
     n = x_t.shape[0]
-    block = BLOCK_ROWS * LANES
-    assert n % block == 0, (n, block)
     assert not in_place or delta.dtype == x_t.dtype, (delta.dtype, x_t.dtype)
-    g = n // block
+    g = _steps(n)
     shaped = lambda a: a.reshape(g * BLOCK_ROWS, LANES)
+    in_specs, operands = _with_scales(
+        [pl.BlockSpec((1, 1), lambda i: (0, 0)), _TILE, _TILE],  # eta bcast
+        [eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(delta)],
+        delta, scales, g, BLOCK_ROWS)
     out = pl.pallas_call(
         _axpy_kernel,
         grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),          # eta broadcast
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
+        in_specs=in_specs,
+        out_specs=_TILE,
         out_shape=jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
         input_output_aliases={2: 0} if in_place else {},
-        name="fedagg_axpy",
+        name=_name("fedagg_axpy", scales),
         interpret=resolve_interpret(interpret),
-    )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(delta))
+    )(*operands)
     return out.reshape(n)
 
 
@@ -220,7 +300,7 @@ def _nt_dot(a: jax.Array, b: jax.Array) -> jax.Array:
                                preferred_element_type=jnp.float32)
 
 
-def _gram_partials(xt, xs, d, dist_ref, dn_ref, c_ref, g_ref):
+def _norms_batched_kernel(xt_ref, xs_ref, d_ref, *refs):
     """Multi-delta phase 1 on one f32 tile of x_t (rows, LANES) against B
     stacked (stale, delta) tiles (B, rows, LANES). Beyond the per-update
     norms, emits the cross terms needed to make the batched apply
@@ -234,6 +314,9 @@ def _gram_partials(xt, xs, d, dist_ref, dn_ref, c_ref, g_ref):
     The two Gram blocks go through the MXU as (B, tile) x (B, tile)
     contractions.
     """
+    scales, (dist_ref, dn_ref, c_ref, g_ref) = _payload(refs, 4)
+    xt, xs = _f32(xt_ref[...]), _f32(xs_ref[...])
+    d = _decode(d_ref[...], scales)
     b = d.shape[0]
     d = d.reshape(b, -1)
     s = (xt[None] - xs).reshape(b, -1)              # drift vectors
@@ -241,11 +324,6 @@ def _gram_partials(xt, xs, d, dist_ref, dn_ref, c_ref, g_ref):
     g_ref[0] = _nt_dot(d, d)
     dist_ref[0] = jnp.sum(s * s, axis=1, keepdims=True)
     dn_ref[0] = jnp.sum(d * d, axis=1, keepdims=True)
-
-
-def _norms_batched_kernel(xt_ref, xs_ref, d_ref, *out_refs):
-    _gram_partials(_f32(xt_ref[...]), _f32(xs_ref[...]), _f32(d_ref[...]),
-                   *out_refs)
 
 
 def _gram_outputs(g: int, b: int):
@@ -258,68 +336,67 @@ def _gram_outputs(g: int, b: int):
     return specs, shapes
 
 
-def _sum_gram(dist, dn, c, gram):
-    return (jnp.sum(dist, axis=0)[:, 0], jnp.sum(dn, axis=0)[:, 0],
-            jnp.sum(c, axis=0), jnp.sum(gram, axis=0))
-
-
 def fedagg_norms_batched(x_t: jax.Array, x_stales: jax.Array,
-                         deltas: jax.Array, *,
+                         deltas: jax.Array,
+                         scales: Optional[jax.Array] = None, *,
                          interpret: Optional[bool] = None):
     """Batched phase 1 over B concurrent arrivals in ONE grid sweep.
 
-    Inputs: x_t (n,), x_stales (B, n), deltas (B, n); n a BLOCK multiple
-    (zero-padded by ops.py — padding contributes 0 to every sum).
+    Inputs: x_t (n,), x_stales (B, n), deltas (B, n) — f32/bf16, or int8
+    with ``scales`` (B, n // QBLOCK); n a BLOCK multiple (zero-padded by
+    ops.py — padding contributes 0 to every sum).
     Returns (dist0_sq (B,), dn_sq (B,), cross (B, B), gram (B, B)) f32,
-    summed over blocks. Each grid step keeps (2B+1) operand tiles resident,
-    so rows-per-step shrinks with B past the knee to bound VMEM.
+    summed over blocks, all on the decoded deltas. Each grid step keeps
+    (2B+1) operand tiles resident, so rows-per-step shrinks with B past
+    the knee to bound VMEM; narrower deltas move the knee out.
     """
     interpret = resolve_interpret(interpret)
     b, n = deltas.shape
     assert x_t.shape == (n,) and x_stales.shape == (b, n)
     rows = _batched_rows(b, n, interpret, deltas.dtype.itemsize)
-    block = rows * LANES
-    assert n % (BLOCK_ROWS * LANES) == 0, (n, BLOCK_ROWS * LANES)
-    g = n // block
+    g = _steps(n, rows)
     shaped1 = lambda a: a.reshape(g * rows, LANES)
     shapedb = lambda a: a.reshape(b, g * rows, LANES)
     specs, shapes = _gram_outputs(g, b)
+    in_specs, operands = _with_scales(
+        [pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+         pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
+         pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0))],
+        [shaped1(x_t), shapedb(x_stales), shapedb(deltas)],
+        deltas, scales, g, rows)
     out = pl.pallas_call(
         _norms_batched_kernel,
         grid=(g,),
-        in_specs=[
-            pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
-            pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=specs,
         out_shape=shapes,
         compiler_params=_batched_params(interpret),
-        name="fedagg_norms_batched",
+        name=_name("fedagg_norms_batched", scales),
         interpret=interpret,
-    )(shaped1(x_t), shapedb(x_stales), shapedb(deltas))
-    return _sum_gram(*out)
+    )(*operands)
+    dist, dn, c, gram = out
+    return (jnp.sum(dist, axis=0)[:, 0], jnp.sum(dn, axis=0)[:, 0],
+            jnp.sum(c, axis=0), jnp.sum(gram, axis=0))
 
 
-def _apply_rows(etas_ref, xt_ref, delta, b: int, out_ref):
-    """out = x_t + sum_i etas[i] * delta(i) on one tile, ``delta(i)`` the
-    f32 (rows, LANES) tile of delta i. Accumulated on the VPU in arrival
-    order — the exact f32 arithmetic of B sequential AXPYs, where an MXU
-    ``etas @ deltas`` would round every delta to bf16."""
+def _apply_batched_kernel(etas_ref, xt_ref, d_ref, *refs):
+    """out = x_t + sum_i etas[i] * delta_i on one tile, each delta tile
+    decoded to f32. Accumulated on the VPU in arrival order — the exact
+    f32 arithmetic of B sequential AXPYs, where an MXU ``etas @ deltas``
+    would round every delta to bf16."""
+    s, (out_ref,) = _payload(refs, 1)
     acc = _f32(xt_ref[...])
-    for i in range(b):
-        acc = acc + etas_ref[0, i] * delta(i)
+    for i in range(d_ref.shape[0]):
+        acc = acc + etas_ref[0, i] * _decode(
+            d_ref[i], None if s is None else s[i:i + 1])
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
-def _apply_batched_kernel(etas_ref, xt_ref, d_ref, out_ref):
-    _apply_rows(etas_ref, xt_ref, lambda i: _f32(d_ref[i]), d_ref.shape[0],
-                out_ref)
-
-
 def fedagg_apply_batched(x_t: jax.Array, deltas: jax.Array, etas: jax.Array,
-                         *, interpret: Optional[bool] = None) -> jax.Array:
-    """Batched Eq.(5): x_t + sum_b etas[b] * deltas[b] in ONE grid sweep.
+                         scales: Optional[jax.Array] = None, *,
+                         interpret: Optional[bool] = None) -> jax.Array:
+    """Batched Eq.(5): x_t + sum_b etas[b] * deltas[b] in ONE grid sweep,
+    ``deltas`` decoded as in :func:`fedagg_norms_batched`.
 
     With etas from ``sequential_batch_schedule`` this equals applying the B
     updates one at a time (Eq.(5) is linear in the deltas), while reading
@@ -329,34 +406,33 @@ def fedagg_apply_batched(x_t: jax.Array, deltas: jax.Array, etas: jax.Array,
     b, n = deltas.shape
     assert x_t.shape == (n,) and etas.shape == (b,)
     rows = _batched_rows(b, n, interpret, deltas.dtype.itemsize)
-    block = rows * LANES
-    assert n % (BLOCK_ROWS * LANES) == 0, (n, BLOCK_ROWS * LANES)
-    g = n // block
+    g = _steps(n, rows)
+    in_specs, operands = _with_scales(
+        [pl.BlockSpec((1, b), lambda i: (0, 0)),          # etas broadcast
+         pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+         pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0))],
+        [etas.reshape(1, b).astype(jnp.float32),
+         x_t.reshape(g * rows, LANES), deltas.reshape(b, g * rows, LANES)],
+        deltas, scales, g, rows)
     out = pl.pallas_call(
         _apply_batched_kernel,
         grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, b), lambda i: (0, 0)),          # etas broadcast
-            pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * rows, LANES), x_t.dtype),
         compiler_params=_batched_params(interpret),
-        name="fedagg_apply_batched",
+        name=_name("fedagg_apply_batched", scales),
         interpret=interpret,
-    )(etas.reshape(1, b).astype(jnp.float32),
-      x_t.reshape(g * rows, LANES), deltas.reshape(b, g * rows, LANES))
+    )(*operands)
     return out.reshape(n)
 
 
 def _fused_kernel(scal_ref, xt_ref, xs_ref, d_ref, out_ref, dist_ref,
                   dn_ref):
-    """Beyond-paper single-phase variant for the displacement-GMIS server:
-    dist is known a-priori (see DESIGN.md §3), so gamma/eta are computed on
-    the host and the whole aggregation is ONE pass: read (x_t, delta),
-    write x_{t+1}, and opportunistically emit the partial norms needed for
-    the *next* gamma bookkeeping."""
+    """Beyond-paper single-phase variant: with eta known before the sweep
+    the whole aggregation is ONE pass — read (x_t, delta), write
+    x_{t+1} — that also emits the partial norms for the *next* gamma
+    bookkeeping. No server path calls it yet (DESIGN.md §4.2)."""
     eta = scal_ref[0, 0]
     xt = _f32(xt_ref[...])
     d = _f32(d_ref[...])
@@ -369,27 +445,17 @@ def _fused_kernel(scal_ref, xt_ref, xs_ref, d_ref, out_ref, dist_ref,
 def fedagg_fused(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
                  eta: jax.Array, *, interpret: Optional[bool] = None):
     """One-pass: returns (x_t + eta*delta, (dist^2, ||delta||^2) partials
-    summed). Used when eta is precomputed (displacement mode) but the norms
-    are still wanted for telemetry/controller."""
+    summed), for a caller that knows eta before the sweep but still wants
+    the norms for telemetry or the controller."""
     n = x_t.shape[0]
-    block = BLOCK_ROWS * LANES
-    assert n % block == 0, (n, block)
-    g = n // block
+    g = _steps(n)
     shaped = lambda a: a.reshape(g * BLOCK_ROWS, LANES)
     spec, shape = _partials(g)
     out, dist, dn = pl.pallas_call(
         _fused_kernel,
         grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            spec, spec,
-        ],
+        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0))] + [_TILE] * 3,
+        out_specs=[_TILE, spec, spec],
         out_shape=[
             jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
             shape, shape,
@@ -399,204 +465,3 @@ def fedagg_fused(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array,
     )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(x_stale),
       shaped(delta))
     return out.reshape(n), jnp.stack([jnp.sum(dist), jnp.sum(dn)])
-
-
-# ------------------------------------------------- quantization-fused path --
-# Compressed delta transport (DESIGN.md §13): deltas arrive as per-block-
-# scaled int8 (one f32 scale per QBLOCK elements, repro.core.compression)
-# and are dequantized INSIDE the grid step — one upcast + one broadcast
-# multiply per scale block — so the f32 delta vector is never
-# materialized in HBM. bf16 deltas need none of this: the f32 kernels
-# above upcast tiles on load, so bf16 rides them unchanged.
-#
-# Scale layout: each grid step reads its tile's scales as one lane-major
-# row, (1, spb) for a single delta and (B, spb) for a batch, from a
-# (steps, 1 or B, spb) array whose block spans the last two dims.
-
-def _scaled(v: jax.Array, s_row: jax.Array) -> jax.Array:
-    """Multiply each QBLOCK_ROWS-row block of the f32 tile ``v`` (rows,
-    LANES) by its scale; ``s_row`` (1, spb) holds one scale per block.
-    The row turns into a column by a small transpose, never by a reshape
-    that moves lanes into sublanes."""
-    spb = s_row.shape[1]
-    col = jnp.transpose(s_row)[:, :, None]             # (spb, 1, 1)
-    return (v.reshape(spb, QBLOCK_ROWS, LANES) * col).reshape(v.shape)
-
-
-def _dequant_tile(q, s):
-    """Dequantize one VMEM tile. ``q`` int8 (rows, LANES) with ``s`` (1,
-    spb), or (B, rows, LANES) with ``s`` (B, spb). Returns the f32
-    tile(s)."""
-    v = q.astype(jnp.float32)
-    if q.ndim == 2:
-        return _scaled(v, s)
-    return jnp.stack([_scaled(v[i], s[i:i + 1]) for i in range(q.shape[0])])
-
-
-def _scale_rows(scales: jax.Array, g: int) -> jax.Array:
-    """(n // QBLOCK,) scales -> (g, 1, spb); (B, n // QBLOCK) -> (g, B,
-    spb): grid step i reads row block i."""
-    if scales.ndim == 1:
-        return scales.reshape(g, 1, -1)
-    return scales.reshape(scales.shape[0], g, -1).transpose(1, 0, 2)
-
-
-def _norms_q_kernel(xt_ref, xs_ref, q_ref, s_ref, dist_ref, dn_ref):
-    diff = _f32(xt_ref[...]) - _f32(xs_ref[...])
-    d = _dequant_tile(q_ref[...], s_ref[0])
-    dist_ref[...] = _fold(diff * diff)
-    dn_ref[...] = _fold(d * d)
-
-
-def fedagg_norms_q(x_t: jax.Array, x_stale: jax.Array, q: jax.Array,
-                   scales: jax.Array, *,
-                   interpret: Optional[bool] = None) -> jax.Array:
-    """Quant-fused phase 1: like :func:`fedagg_norms` but the delta arrives
-    as int8 ``q`` (n,) + f32 ``scales`` (n // QBLOCK,). The emitted
-    ||delta||^2 is the DEQUANTIZED norm — exactly what the AXPY applies, so
-    screening/gamma computed from it see the transported values."""
-    n = x_t.shape[0]
-    block = BLOCK_ROWS * LANES
-    assert n % block == 0, (n, block)
-    assert q.shape == (n,) and scales.shape == (n // QBLOCK,), (
-        q.shape, scales.shape, n)
-    g = n // block
-    spb = BLOCK_ROWS // QBLOCK_ROWS
-    shaped = lambda a: a.reshape(g * BLOCK_ROWS, LANES)
-    spec, shape = _partials(g)
-    dist, dn = pl.pallas_call(
-        _norms_q_kernel,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1, spb), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[spec, spec],
-        out_shape=[shape, shape],
-        name="fedagg_norms_q",
-        interpret=resolve_interpret(interpret),
-    )(shaped(x_t), shaped(x_stale), shaped(q), _scale_rows(scales, g))
-    return jnp.stack([jnp.sum(dist), jnp.sum(dn)])
-
-
-def _axpy_q_kernel(eta_ref, xt_ref, q_ref, s_ref, out_ref):
-    eta = eta_ref[0, 0]
-    d = _dequant_tile(q_ref[...], s_ref[0])
-    out_ref[...] = (xt_ref[...].astype(jnp.float32) + eta * d
-                    ).astype(out_ref.dtype)
-
-
-def fedagg_axpy_q(x_t: jax.Array, q: jax.Array, scales: jax.Array,
-                  eta: jax.Array, *,
-                  interpret: Optional[bool] = None) -> jax.Array:
-    """Quant-fused Eq.(5): x_t + eta * dequant(q, scales), one sweep."""
-    n = x_t.shape[0]
-    block = BLOCK_ROWS * LANES
-    assert n % block == 0, (n, block)
-    assert q.shape == (n,) and scales.shape == (n // QBLOCK,)
-    g = n // block
-    spb = BLOCK_ROWS // QBLOCK_ROWS
-    shaped = lambda a: a.reshape(g * BLOCK_ROWS, LANES)
-    out = pl.pallas_call(
-        _axpy_q_kernel,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),          # eta broadcast
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1, spb), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g * BLOCK_ROWS, LANES), x_t.dtype),
-        name="fedagg_axpy_q",
-        interpret=resolve_interpret(interpret),
-    )(eta.reshape(1, 1).astype(jnp.float32), shaped(x_t), shaped(q),
-      _scale_rows(scales, g))
-    return out.reshape(n)
-
-
-def _norms_batched_q_kernel(xt_ref, xs_ref, q_ref, s_ref, *out_refs):
-    _gram_partials(_f32(xt_ref[...]), _f32(xs_ref[...]),
-                   _dequant_tile(q_ref[...], s_ref[0]), *out_refs)
-
-
-def fedagg_norms_batched_q(x_t: jax.Array, x_stales: jax.Array,
-                           qs: jax.Array, scales: jax.Array, *,
-                           interpret: Optional[bool] = None):
-    """Batched phase 1 over B quantized arrivals: like
-    :func:`fedagg_norms_batched` with ``qs`` (B, n) int8 + ``scales``
-    (B, n // QBLOCK) f32 resident instead of f32 deltas — the delta tiles
-    cost 1 byte/element, so the free-batch knee moves from 15 to 24
-    (``batched_b_max(1)``). All four outputs are computed on the
-    dequantized values."""
-    interpret = resolve_interpret(interpret)
-    b, n = qs.shape
-    assert x_t.shape == (n,) and x_stales.shape == (b, n)
-    assert scales.shape == (b, n // QBLOCK), (scales.shape, b, n // QBLOCK)
-    rows = _batched_rows(b, n, interpret, 1)
-    block = rows * LANES
-    assert n % (BLOCK_ROWS * LANES) == 0, (n, BLOCK_ROWS * LANES)
-    g = n // block
-    spb = rows // QBLOCK_ROWS
-    shaped1 = lambda a: a.reshape(g * rows, LANES)
-    shapedb = lambda a: a.reshape(b, g * rows, LANES)
-    specs, shapes = _gram_outputs(g, b)
-    out = pl.pallas_call(
-        _norms_batched_q_kernel,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
-            pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
-            pl.BlockSpec((1, b, spb), lambda i: (i, 0, 0)),
-        ],
-        out_specs=specs,
-        out_shape=shapes,
-        compiler_params=_batched_params(interpret),
-        name="fedagg_norms_batched_q",
-        interpret=interpret,
-    )(shaped1(x_t), shapedb(x_stales), shapedb(qs), _scale_rows(scales, g))
-    return _sum_gram(*out)
-
-
-def _apply_batched_q_kernel(etas_ref, xt_ref, q_ref, s_ref, out_ref):
-    scales = s_ref[0]                               # (B, spb)
-    _apply_rows(etas_ref, xt_ref,
-                lambda i: _scaled(q_ref[i].astype(jnp.float32),
-                                  scales[i:i + 1]),
-                q_ref.shape[0], out_ref)
-
-
-def fedagg_apply_batched_q(x_t: jax.Array, qs: jax.Array, scales: jax.Array,
-                           etas: jax.Array, *,
-                           interpret: Optional[bool] = None) -> jax.Array:
-    """Batched quant-fused Eq.(5): x_t + sum_b etas[b] * dequant(qs[b])."""
-    interpret = resolve_interpret(interpret)
-    b, n = qs.shape
-    assert x_t.shape == (n,) and etas.shape == (b,)
-    assert scales.shape == (b, n // QBLOCK)
-    rows = _batched_rows(b, n, interpret, 1)
-    block = rows * LANES
-    assert n % (BLOCK_ROWS * LANES) == 0, (n, BLOCK_ROWS * LANES)
-    g = n // block
-    spb = rows // QBLOCK_ROWS
-    out = pl.pallas_call(
-        _apply_batched_q_kernel,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, b), lambda i: (0, 0)),          # etas broadcast
-            pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((b, rows, LANES), lambda i: (0, i, 0)),
-            pl.BlockSpec((1, b, spb), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g * rows, LANES), x_t.dtype),
-        compiler_params=_batched_params(interpret),
-        name="fedagg_apply_batched_q",
-        interpret=interpret,
-    )(etas.reshape(1, b).astype(jnp.float32),
-      x_t.reshape(g * rows, LANES), qs.reshape(b, g * rows, LANES),
-      _scale_rows(scales, g))
-    return out.reshape(n)

@@ -64,3 +64,16 @@ def reduced_f32(arch_id: str, **kw):
         cfg = dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe, impl="dense"))
     return dataclasses.replace(cfg, dtype="float32")
+
+
+def flat_aggregate_tree(x_t, x_stale, delta, **kw):
+    """One ``ops.flat_aggregate`` step on pytrees: each flattened through
+    one ``FlatSpec`` padded to the kernel BLOCK, the new vector
+    unflattened back to ``x_t``'s structure and dtypes."""
+    from repro.core.aggregation import AggregationResult
+    from repro.kernels.fedagg import ops
+    from repro.utils import pytree as pt
+    spec = pt.FlatSpec(x_t, block=ops._BLOCK)
+    new, *scalars = ops.flat_aggregate(
+        spec.flatten(x_t), spec.flatten(x_stale), spec.flatten(delta), **kw)
+    return AggregationResult(spec.unflatten(new), *scalars)

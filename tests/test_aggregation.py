@@ -99,11 +99,11 @@ class TestAggregationEdgeCasesBothBackends:
     LAM, EPS = 2.0, 0.5
 
     def _both(self, x_t, x_s, d, cap=0.0):
-        from repro.kernels.fedagg.ops import asyncfeded_aggregate_pallas
+        from conftest import flat_aggregate_tree
         r_tree = agg.asyncfeded_aggregate(x_t, x_s, d, lam=self.LAM,
                                           eps=self.EPS, cap=cap)
-        r_flat = asyncfeded_aggregate_pallas(x_t, x_s, d, lam=self.LAM,
-                                             eps=self.EPS, cap=cap)
+        r_flat = flat_aggregate_tree(x_t, x_s, d, lam=self.LAM,
+                                     eps=self.EPS, cap=cap)
         return r_tree, r_flat
 
     def _assert_agree(self, r_tree, r_flat):

@@ -1,5 +1,5 @@
 """Compressed delta transport (DESIGN.md §13): quantization properties,
-quant-fused kernel parity vs the dequant-then-f32 reference, end-to-end
+int8 sweep parity vs the dequant-then-f32 reference, end-to-end
 server equivalence across backends, error-feedback residual lifecycle,
 and the budget-law cohort-width gain."""
 import dataclasses
@@ -92,7 +92,8 @@ class TestQuantize:
 
 # ----------------------------------------------------- quant-fused kernels --
 class TestQuantKernels:
-    """Parity vs dequantize-then-f32 through the ref.py oracles."""
+    """The sweeps with int8 ``scales``: parity vs dequantize-then-f32
+    through the ref.py oracles."""
 
     @pytest.mark.parametrize("nblocks", [1, 2, 5])
     def test_norms_q(self, nblocks):
@@ -100,7 +101,7 @@ class TestQuantKernels:
         xt, xs = _vec(n, 0, 1.0), _vec(n, 1, 1.0)
         cd = compression.quantize_vec(_vec(n, 2), "int8", n)
         d = compression.dequantize(cd)
-        got = fedagg.fedagg_norms_q(xt, xs, cd.q, cd.scales)
+        got = fedagg.fedagg_norms(xt, xs, cd.q, cd.scales)
         want = fedagg_ref.norms_ref(xt, xs, d)
         np.testing.assert_allclose(got, want, rtol=1e-5)
 
@@ -109,7 +110,7 @@ class TestQuantKernels:
         n = BLOCK * nblocks
         xt = _vec(n, 0, 1.0)
         cd = compression.quantize_vec(_vec(n, 1), "int8", n)
-        got = fedagg.fedagg_axpy_q(xt, cd.q, cd.scales, jnp.float32(0.37))
+        got = fedagg.fedagg_axpy(xt, cd.q, jnp.float32(0.37), cd.scales)
         want = fedagg_ref.axpy_ref(xt, compression.dequantize(cd),
                                    jnp.float32(0.37))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
@@ -123,7 +124,7 @@ class TestQuantKernels:
         cds = [compression.quantize_vec(_vec(n, 20 + i), "int8", n)
                for i in range(b)]
         ds = jnp.stack([compression.dequantize(c) for c in cds])
-        got = fedagg.fedagg_norms_batched_q(
+        got = fedagg.fedagg_norms_batched(
             xt, xs, jnp.stack([c.q for c in cds]),
             jnp.stack([c.scales for c in cds]))
         want = fedagg_ref.norms_batched_ref(xt, xs, ds)
@@ -138,9 +139,9 @@ class TestQuantKernels:
                for i in range(b)]
         ds = jnp.stack([compression.dequantize(c) for c in cds])
         etas = jnp.arange(1, b + 1, dtype=jnp.float32) / 10
-        got = fedagg.fedagg_apply_batched_q(
-            xt, jnp.stack([c.q for c in cds]),
-            jnp.stack([c.scales for c in cds]), etas)
+        got = fedagg.fedagg_apply_batched(
+            xt, jnp.stack([c.q for c in cds]), etas,
+            jnp.stack([c.scales for c in cds]))
         want = fedagg_ref.apply_batched_ref(xt, ds, etas)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
@@ -149,15 +150,16 @@ class TestQuantKernels:
         xt, xs = _vec(n, 0, 1.0), _vec(n, 1, 1.0)
         cd = compression.quantize_vec(_vec(n, 2), "int8", n)
         d = compression.dequantize(cd)
-        got = ops.flat_aggregate_q(xt, xs, cd.q, cd.scales, lam=1.0, eps=1.0)
+        got = ops.flat_aggregate(xt, xs, cd.q, scales=cd.scales, lam=1.0,
+                                 eps=1.0)
         want = ops.flat_aggregate(xt, xs, d, lam=1.0, eps=1.0)
         for g, w in zip(got, want):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=1e-5, atol=1e-7)
 
     def test_bf16_payload_rides_f32_kernels_exactly(self):
-        # no quant kernels needed for bf16: the f32 kernels upcast tiles
-        # on load, so feeding the bf16 payload is exact f32 accumulation
+        # bf16 needs no scales: the sweeps upcast tiles on load, so
+        # feeding the bf16 payload is exact f32 accumulation
         n = BLOCK
         xt, xs = _vec(n, 0, 1.0), _vec(n, 1, 1.0)
         cd = compression.quantize_vec(_vec(n, 2), "bf16", n)

@@ -154,9 +154,41 @@ class TestShardedServerEquivalence:
         assert r1.total_drains == r2.total_drains
         assert_same_run(r1, r2)
 
+    def test_burst_drain_reads_the_gram_sweep_in_a_span(self, multidevice,
+                                                        tmp_path):
+        """The sharded batched drain resolves its schedule through the
+        same host step as the replicated one: each batched drain reads
+        the psum'd Gram sweep once, in a ``server.sync`` span inside its
+        ``server.kernels`` span."""
+        import glob
+        from jax.profiler import ProfileData
+        task = configs.SYNTHETIC_1_1
+        fed = dataclasses.replace(task.fed, backend="pallas", model_shards=4)
+        with jax.profiler.trace(str(tmp_path)):
+            run_sim(task, fed, batch_window=0.05, max_time=1e9,
+                    max_updates=12, eval_every=100)
+        path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                          recursive=True)
+        spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                  dict(ev.stats))
+                 for plane in ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:") for line in plane.lines
+                 for ev in line.events if ev.name.startswith("server.")]
+        within = lambda name, outer: [s for s in spans if s[0] == name
+                                      and outer[1] <= s[1]
+                                      and s[2] <= outer[2]]
+        batched = [s for s in spans if s[0] == "server.drain"
+                   and s[3]["path"] == "batched"]
+        assert batched
+        for d in batched:
+            kernels, = within("server.kernels", d)
+            assert [s[3]["reads"] for s in within("server.sync", d)] == [4]
+            assert len(within("server.sync", kernels)) == 1
+
     def test_int8_burst(self, multidevice):
-        """int8 payloads through the sharded `_q` twins — scales stay
-        adjacent to their q blocks under the contiguous model split."""
+        """int8 payloads with their block scales through the sharded
+        entry points — scales stay adjacent to their q blocks under the
+        contiguous model split."""
         task = configs.SYNTHETIC_1_1
         fed_p = dataclasses.replace(task.fed, backend="pallas",
                                     delta_compression="int8")

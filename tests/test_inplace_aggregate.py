@@ -63,15 +63,14 @@ def _tree(seed, one_leaf=False):
 
 def _kernel_stats(srv, monkeypatch):
     """Record the ``in_place`` keyword of each ``flat_aggregate`` call and
-    whether a displacement call ran."""
+    whether it carried a displacement ``drift``."""
     seen = []
-    for name in ("flat_aggregate", "flat_aggregate_displacement"):
-        agg = srv._agg[name]
+    agg = srv._agg["flat_aggregate"]
 
-        def spy(*a, _agg=agg, _name=name, **kw):
-            seen.append(kw.get("in_place", _name))
-            return _agg(*a, **kw)
-        monkeypatch.setitem(srv._agg, name, spy)
+    def spy(*a, **kw):
+        seen.append((kw["in_place"], kw["drift"] is not None))
+        return agg(*a, **kw)
+    monkeypatch.setitem(srv._agg, "flat_aggregate", spy)
     return seen
 
 
@@ -100,8 +99,7 @@ def test_the_callers_delta_stays_readable(case, in_place, monkeypatch):
     before = jax.tree.map(np.array, delta)
     seen = _kernel_stats(srv, monkeypatch)
     srv.on_update(ClientUpdate(0, 1, 2, delta))
-    assert seen == ["flat_aggregate_displacement" if case == "displacement"
-                    else in_place]
+    assert seen == [(in_place, case == "displacement")]
     for leaf, want in zip(jax.tree.leaves(delta), jax.tree.leaves(before)):
         assert not getattr(leaf, "is_deleted", lambda: False)()
         np.testing.assert_array_equal(np.asarray(leaf), want)

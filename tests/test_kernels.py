@@ -5,12 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import flat_aggregate_tree
 from repro.kernels.fedagg import fedagg
 from repro.kernels.fedagg import ref as fedagg_ref
-from repro.kernels.fedagg.ops import (asyncfeded_aggregate_batched_pallas,
-                                      asyncfeded_aggregate_pallas,
-                                      flat_aggregate, flat_aggregate_batched,
-                                      pad_flat_vector)
+from repro.kernels.fedagg.ops import flat_aggregate_batched, pad_flat_vector
 from repro.kernels.rglru.ops import rglru_pallas
 from repro.kernels.rglru.ref import rglru_scan_ref
 from repro.kernels.rglru.rglru import rglru_scan
@@ -68,7 +66,7 @@ class TestFedAgg:
                       jax.random.normal(k, (2, 3, 5))]}
         stale = jax.tree.map(lambda x: x + 0.02, tree)
         delta = jax.tree.map(lambda x: x * 0.01, tree)
-        r1 = asyncfeded_aggregate_pallas(tree, stale, delta, lam=2.0, eps=1.0)
+        r1 = flat_aggregate_tree(tree, stale, delta, lam=2.0, eps=1.0)
         r2 = asyncfeded_aggregate(tree, stale, delta, lam=2.0, eps=1.0)
         np.testing.assert_allclose(float(r1.gamma), float(r2.gamma), rtol=1e-4)
         for l1, l2 in zip(jax.tree.leaves(r1.params),
@@ -79,7 +77,8 @@ class TestFedAgg:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_padding_path_odd_sizes(self, n, dtype):
         """Sizes that are NOT BLOCK multiples go through the zero-padding
-        path in ops.py; padding must be value-transparent."""
+        path of a BLOCK-padded FlatSpec; padding must be
+        value-transparent."""
         from repro.core.aggregation import asyncfeded_aggregate
         tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (n,), dtype)}
         stale = {"w": (tree["w"] + jnp.asarray(0.03, dtype)).astype(dtype)}
@@ -87,7 +86,7 @@ class TestFedAgg:
                        * 0.02).astype(dtype)}
         vec = pad_flat_vector(jnp.ravel(tree["w"]).astype(jnp.float32))
         assert vec.shape[0] % BLOCK == 0
-        r1 = asyncfeded_aggregate_pallas(tree, stale, delta, lam=1.5, eps=0.5)
+        r1 = flat_aggregate_tree(tree, stale, delta, lam=1.5, eps=0.5)
         r2 = asyncfeded_aggregate(tree, stale, delta, lam=1.5, eps=0.5)
         tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
         np.testing.assert_allclose(float(r1.gamma), float(r2.gamma),
@@ -166,8 +165,9 @@ class TestFedAggBatched:
         np.testing.assert_allclose(new, rnew, rtol=1e-4, atol=1e-5)
 
     def test_batched_pytree_wrapper_odd_sizes(self):
-        """Non-BLOCK-multiple pytrees through the batched wrapper (padding
-        path) vs B sequential core aggregations."""
+        """Non-BLOCK-multiple pytrees through a BLOCK-padded FlatSpec and
+        the batched entry point vs B sequential core aggregations."""
+        from repro.utils import pytree as pt
         from repro.core.aggregation import asyncfeded_aggregate
         k = jax.random.PRNGKey(5)
         tree = {"a": jax.random.normal(k, (41, 13)),
@@ -178,8 +178,11 @@ class TestFedAggBatched:
                 lambda x: x + 0.01 * (i + 1), tree))
             deltas.append(jax.tree.map(
                 lambda x: x * 0.005 * (i + 1), tree))
-        new, etas, gammas, _, _ = asyncfeded_aggregate_batched_pallas(
-            tree, stales, deltas, lam=1.0, eps=1.0)
+        spec = pt.FlatSpec(tree, block=BLOCK)
+        new, etas, gammas, _, _, _ = flat_aggregate_batched(
+            spec.flatten(tree), jnp.stack([spec.flatten(t) for t in stales]),
+            jnp.stack([spec.flatten(t) for t in deltas]), lam=1.0, eps=1.0)
+        new = spec.unflatten(new)
         cur = tree
         for i in range(3):
             res = asyncfeded_aggregate(cur, stales[i], deltas[i],
@@ -192,11 +195,11 @@ class TestFedAggBatched:
 
 
 class TestFedAggSharded:
-    """Model-sharded twins (kernels/fedagg/sharded.py) vs the replicated
-    ops, at shard counts that do NOT divide the true size (the remainder
-    lives in zero padding) and non-pow2 padded block counts, including
-    the int8 ``_q`` entry points and bf16 payloads (which ride the
-    uncompressed kernels — f32 tiles upcast on load). shards=1 is a
+    """Model-sharded entry points (kernels/fedagg/sharded.py) vs the
+    replicated ops, at shard counts that do NOT divide the true size (the
+    remainder lives in zero padding) and non-pow2 padded block counts,
+    including int8 payloads with their block ``scales``, displacement
+    ``drift``, and bf16 payloads (f32 tiles upcast on load). shards=1 is a
     valid 1-device mesh and runs everywhere; shards>1 takes the
     ``multidevice`` fixture (tier1-multidevice CI, or the re-exec in
     test_flat_sharded.py)."""
@@ -265,24 +268,23 @@ class TestFedAggSharded:
         from repro.kernels.fedagg import ops, sharded
         xt, disp, d = self._padded(2 * BLOCK + 33, 2, seed=9)
         z = jnp.zeros_like(xt)
-        got = sharded.flat_aggregate_displacement(
-            xt, disp, d, z, lam=2.0, eps=1.0, shards=2)
-        want = ops.flat_aggregate_displacement(xt, disp, d, z,
-                                               lam=2.0, eps=1.0)
+        got = sharded.flat_aggregate(xt, z, d, drift=disp, lam=2.0, eps=1.0,
+                                     shards=2)
+        want = ops.flat_aggregate(xt, z, d, drift=disp, lam=2.0, eps=1.0)
         self._assert_single(got, want)
 
     @pytest.mark.parametrize("shards", [2, 4])
     def test_q_int8_nondividing(self, multidevice, shards):
-        """int8 `_q` twins: scales stay adjacent to the q blocks they
+        """int8 payloads: scales stay adjacent to the q blocks they
         dequantize under the contiguous model split."""
         from repro.core import compression
         from repro.kernels.fedagg import ops, sharded
         xt, xs, d = self._padded(2 * BLOCK * shards - 917, shards, seed=3)
         cd = compression.quantize_vec(d, "int8", int(d.shape[0]))
-        got = sharded.flat_aggregate_q(xt, xs, cd.q, cd.scales,
-                                       lam=2.0, eps=1.0, shards=shards)
-        want = ops.flat_aggregate_q(xt, xs, cd.q, cd.scales,
-                                    lam=2.0, eps=1.0)
+        got = sharded.flat_aggregate(xt, xs, cd.q, scales=cd.scales,
+                                     lam=2.0, eps=1.0, shards=shards)
+        want = ops.flat_aggregate(xt, xs, cd.q, scales=cd.scales,
+                                  lam=2.0, eps=1.0)
         self._assert_single(got, want)
 
     def test_displacement_q_int8(self, multidevice):
@@ -291,10 +293,10 @@ class TestFedAggSharded:
         xt, disp, d = self._padded(2 * BLOCK + 1001, 2, seed=13)
         z = jnp.zeros_like(xt)
         cd = compression.quantize_vec(d, "int8", int(d.shape[0]))
-        got = sharded.flat_aggregate_displacement_q(
-            xt, disp, cd.q, cd.scales, z, lam=1.0, eps=1.0, shards=2)
-        want = ops.flat_aggregate_displacement_q(
-            xt, disp, cd.q, cd.scales, z, lam=1.0, eps=1.0)
+        got = sharded.flat_aggregate(xt, z, cd.q, scales=cd.scales,
+                                     drift=disp, lam=1.0, eps=1.0, shards=2)
+        want = ops.flat_aggregate(xt, z, cd.q, scales=cd.scales, drift=disp,
+                                  lam=1.0, eps=1.0)
         self._assert_single(got, want)
 
     def test_batched_nondividing(self, multidevice):
@@ -354,10 +356,10 @@ class TestFedAggSharded:
             for i in range(b)]
         qs = jnp.stack([r.q for r in rows])
         scales = jnp.stack([r.scales for r in rows])
-        new, etas, gammas, *_ = sharded.flat_aggregate_batched_q(
-            xt, xs, qs, scales, lam=2.0, eps=1.0, shards=shards)
-        rnew, retas, rgammas, *_ = ops.flat_aggregate_batched_q(
-            xt, xs, qs, scales, lam=2.0, eps=1.0)
+        new, etas, gammas, *_ = sharded.flat_aggregate_batched(
+            xt, xs, qs, scales=scales, lam=2.0, eps=1.0, shards=shards)
+        rnew, retas, rgammas, *_ = ops.flat_aggregate_batched(
+            xt, xs, qs, scales=scales, lam=2.0, eps=1.0)
         np.testing.assert_allclose(etas, retas, rtol=1e-4, atol=1e-6)
         np.testing.assert_allclose(gammas, rgammas, rtol=1e-4, atol=1e-6)
         np.testing.assert_allclose(np.asarray(jax.device_get(new)),

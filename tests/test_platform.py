@@ -60,7 +60,7 @@ class TestKernelMode:
          "norms_batched": lambda: fedagg.fedagg_norms_batched(x, xs, xs),
          "apply_batched": lambda: fedagg.fedagg_apply_batched(
              x, xs, jnp.ones((3,))),
-         "norms_q": lambda: fedagg.fedagg_norms_q(x, x, q, s)}[kernel]()
+         "norms_q": lambda: fedagg.fedagg_norms(x, x, q, s)}[kernel]()
         # two grid steps of BLOCK_ROWS rows: the compiled schedule, not
         # the interpreter's single whole-vector step
         assert seen == [(False, (2,))]

@@ -84,8 +84,8 @@ class TestSingleChip:
             "norms": (fedagg.fedagg_norms, (vec, vec, vec)),
             "axpy": (fedagg.fedagg_axpy, (vec, vec, eta)),
             "fused": (fedagg.fedagg_fused, (vec, vec, vec, eta)),
-            "norms_q": (fedagg.fedagg_norms_q, (vec, vec, q, s)),
-            "axpy_q": (fedagg.fedagg_axpy_q, (vec, q, s, eta)),
+            "norms_q": (fedagg.fedagg_norms, (vec, vec, q, s)),
+            "axpy_q": (fedagg.fedagg_axpy, (vec, q, eta, s)),
         }[kernel]
         _compile(lambda *a: fn(*a, interpret=False), *args)
 
@@ -101,17 +101,11 @@ class TestSingleChip:
         stales = _sds(one_chip, (b, n))
         deltas = _sds(one_chip, (b, n), dtype)
         etas = _sds(one_chip, (b,))
-        if dtype == jnp.int8:
-            scales = _sds(one_chip, (b, n // QB))
-            _compile(lambda *a: fedagg.fedagg_norms_batched_q(
-                *a, interpret=False), xt, stales, deltas, scales)
-            _compile(lambda *a: fedagg.fedagg_apply_batched_q(
-                *a, interpret=False), xt, deltas, scales, etas)
-        else:
-            _compile(lambda *a: fedagg.fedagg_norms_batched(
-                *a, interpret=False), xt, stales, deltas)
-            _compile(lambda *a: fedagg.fedagg_apply_batched(
-                *a, interpret=False), xt, deltas, etas)
+        scales = (_sds(one_chip, (b, n // QB)),) if dtype == jnp.int8 else ()
+        _compile(lambda *a: fedagg.fedagg_norms_batched(
+            *a, interpret=False), xt, stales, deltas, *scales)
+        _compile(lambda *a: fedagg.fedagg_apply_batched(
+            *a, interpret=False), xt, deltas, etas, *scales)
 
     @pytest.mark.parametrize("direction", ["flatten", "unflatten"])
     def test_staging_keeps_no_model_sized_temporary(self, one_chip,
@@ -161,10 +155,8 @@ def four_chip_mesh(topo, monkeypatch):
     mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 4),
                 ("pod", "model"))
     monkeypatch.setattr(sharded, "fedagg_mesh", lambda shards: mesh)
-    builders = (sharded._aggregate, sharded._aggregate_displacement,
-                sharded._aggregate_q, sharded._aggregate_displacement_q,
-                sharded._norms_batched, sharded._apply_batched,
-                sharded._norms_batched_q, sharded._apply_batched_q)
+    builders = (sharded._aggregate, sharded._norms_batched,
+                sharded._apply_batched)
     for b in builders:
         b.cache_clear()
     yield mesh
@@ -191,22 +183,22 @@ class TestFourChipMesh:
         etas = at(rep, (b,))
         scal = (4, 1.0, 1.0, 0.0, False)
         calls = {
-            "aggregate": [(sharded._aggregate(*scal), (vec, vec, vec),
-                           True)],
-            "displacement": [(sharded._aggregate_displacement(*scal),
+            "aggregate": [(sharded._aggregate(*scal, False, False),
+                           (vec, vec, vec), True)],
+            "displacement": [(sharded._aggregate(*scal, False, True),
                               (vec, vec, vec, vec), True)],
-            "aggregate_q": [(sharded._aggregate_q(*scal), (vec, vec, q, s),
-                             True)],
-            "displacement_q": [(sharded._aggregate_displacement_q(*scal),
+            "aggregate_q": [(sharded._aggregate(*scal, True, False),
+                             (vec, vec, q, s), True)],
+            "displacement_q": [(sharded._aggregate(*scal, True, True),
                                 (vec, vec, q, s, vec), True)],
-            "batched": [(sharded._norms_batched(4, False),
+            "batched": [(sharded._norms_batched(4, False, False),
                          (vec, stack, stack), True),
-                        (sharded._apply_batched(4, False),
+                        (sharded._apply_batched(4, False, False),
                          (vec, stack, etas), False)],
-            "batched_q": [(sharded._norms_batched_q(4, False),
+            "batched_q": [(sharded._norms_batched(4, False, True),
                            (vec, stack, qstack, sstack), True),
-                          (sharded._apply_batched_q(4, False),
-                           (vec, qstack, sstack, etas), False)],
+                          (sharded._apply_batched(4, False, True),
+                           (vec, qstack, etas, sstack), False)],
         }[body]
         for fn, args, psum in calls:
             text = fn.lower(*args).compile().as_text()

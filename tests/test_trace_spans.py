@@ -24,10 +24,11 @@ SERVER_CHILDREN = ("server.flatten", "server.kernels", "server.sync",
                    "server.book", "server.unflatten")
 
 
-def _fed(engine):
+def _fed(engine, compression="off"):
     return dataclasses.replace(configs.SYNTHETIC_1_1.fed, backend="pallas",
                                client_engine=engine, num_clients=5,
-                               k_initial=2, k_max=4)
+                               k_initial=2, k_max=4,
+                               delta_compression=compression)
 
 
 def _spans(trace_dir):
@@ -45,9 +46,10 @@ def _spans(trace_dir):
     return out
 
 
-def _traced_run(tmp_path_factory, engine, window):
-    sim = FederatedSimulation(configs.SYNTHETIC_1_1, _fed(engine),
-                              "asyncfeded", seed=2, batch_window=window)
+def _traced_run(tmp_path_factory, engine, window, compression="off"):
+    sim = FederatedSimulation(configs.SYNTHETIC_1_1,
+                              _fed(engine, compression), "asyncfeded",
+                              seed=2, batch_window=window)
     d = str(tmp_path_factory.mktemp(f"trace-{engine}"))
     with jax.profiler.trace(d):
         res = sim.run(max_time=1e9, max_updates=8, eval_every=5)
@@ -64,6 +66,16 @@ def cohort_run(tmp_path_factory):
     return _traced_run(tmp_path_factory, "cohort", 0.5)
 
 
+@pytest.fixture(scope="module")
+def loop_int8_run(tmp_path_factory):
+    return _traced_run(tmp_path_factory, "loop", 0.0, "int8")
+
+
+@pytest.fixture(scope="module")
+def cohort_int8_run(tmp_path_factory):
+    return _traced_run(tmp_path_factory, "cohort", 0.5, "int8")
+
+
 def _named(spans, name):
     return [s for s in spans if s[0] == name]
 
@@ -72,7 +84,8 @@ def _within(inner, outers):
     return [o for o in outers if o[1] <= inner[1] and inner[2] <= o[2]]
 
 
-@pytest.mark.parametrize("run", ["loop_run", "cohort_run"])
+@pytest.mark.parametrize("run", ["loop_run", "cohort_run", "loop_int8_run",
+                                 "cohort_int8_run"])
 def test_server_spans_nest_inside_the_drain_inside_the_loop(run, request):
     _, res, spans = request.getfixturevalue(run)
     drains = _named(spans, "server.drain")
@@ -112,6 +125,29 @@ def test_batched_drains_read_the_gram_sweep_once(cohort_run):
                  if _within(s, [d])]
         assert len(kernels) == len(sched) == 1
         assert _within(syncs[0], kernels) and _within(sched[0], kernels)
+
+
+@pytest.mark.parametrize("run", ["loop_run", "cohort_run", "loop_int8_run",
+                                 "cohort_int8_run"])
+def test_every_aggregation_stages_and_sweeps_inside_spans(run, request):
+    """Each update of a sequential drain, and each batched drain once,
+    stages its deltas in a ``server.flatten`` span and runs its kernels
+    in a ``server.kernels`` span, whatever the payload; a batched drain
+    reads its Gram sweep once, inside its kernels."""
+    _, _, spans = request.getfixturevalue(run)
+    drains = _named(spans, "server.drain")
+    assert (any(d[3]["path"] == "batched" for d in drains)
+            == run.startswith("cohort"))
+    for d in drains:
+        want = d[3]["B"] if d[3]["path"] == "seq" else 1
+        for name in ("server.flatten", "server.kernels"):
+            inside = [s for s in _named(spans, name) if _within(s, [d])]
+            assert len(inside) == want, (name, d)
+        if d[3]["path"] == "batched":
+            syncs = [s for s in _named(spans, "server.sync")
+                     if _within(s, [d])]
+            assert [s[3]["reads"] for s in syncs] == [4]
+            assert _within(syncs[0], _named(spans, "server.kernels"))
 
 
 def test_server_flatten_counts_host_deltas_it_uploads(loop_run, cohort_run):
