@@ -31,6 +31,7 @@ from collections import OrderedDict
 from typing import Any, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.utils import pytree as pt
 
@@ -100,7 +101,9 @@ class DisplacementGMIS:
 
     def on_aggregate(self, eta, delta: PyTree) -> None:
         """Every server update moves x_t by eta*delta — fold into every
-        outstanding displacement."""
+        outstanding displacement. A host ``eta`` goes to the device once,
+        not once per accumulator."""
+        eta = jnp.asarray(eta)
         for cid in self._disp:
             self._disp[cid] = pt.tree_axpy(eta, delta, self._disp[cid])
 

@@ -362,7 +362,7 @@ class AsyncFedEDServer(AsyncServer):
             res = agg(self.params, stale, upd.delta, lam=fed.lam,
                       eps=fed.eps, cap=fed.staleness_cap)
         self.params = res.params
-        return res.gamma, res.eta, res.dist, res.delta_norm, res.params
+        return jnp.stack([res.gamma, res.eta, res.dist, res.delta_norm])
 
     def _wire_padded(self, cd):
         """A compressed payload's (q, scales) padded to the server's flat
@@ -403,7 +403,7 @@ class AsyncFedEDServer(AsyncServer):
             stale, drift = (
                 (self._zeros_vec(), self.gmis.displacement(upd.client_id))
                 if displaced else (self.gmis.get(upd.snapshot_iter)[0], None))
-            new_vec, gamma, eta, dist, dnorm = self._agg["flat_aggregate"](
+            new_vec, scalars = self._agg["flat_aggregate"](
                 self._flat.vec, stale, d, scales=scales, drift=drift,
                 lam=fed.lam, eps=fed.eps, cap=fed.staleness_cap,
                 in_place=in_place)
@@ -414,7 +414,7 @@ class AsyncFedEDServer(AsyncServer):
                 d = compression.dequantize(
                     dataclasses.replace(cd, q=d, scales=scales))
         self._flat = self._flat.replace(new_vec)
-        return gamma, eta, dist, dnorm, d
+        return scalars, d
 
     def _reject_reply(self, upd: ClientUpdate, raw_norm: float
                       ) -> ServerReply:
@@ -435,26 +435,31 @@ class AsyncFedEDServer(AsyncServer):
             return self._reject_reply(upd, raw_norm)
         upd = upd2
         if self.backend == "pallas":
-            gamma, eta, dist, dnorm, delta = self._aggregate_flat(upd)
+            scalars, delta = self._aggregate_flat(upd)
         else:
             # decompress HERE, not inside _aggregate_pytree: the delta
             # also feeds gmis.on_aggregate below, which folds it into
             # every outstanding displacement accumulator leafwise
             upd = self._decompress(upd)
-            gamma, eta, dist, dnorm, _ = self._aggregate_pytree(upd)
+            scalars = self._aggregate_pytree(upd)
             delta = upd.delta
         # true staleness: tau = t - snapshot at APPLY time, before this
         # update advances the iteration counter — matches FedAsync's lag
         # telemetry so cross-server staleness records are comparable
         lag = self.t - upd.snapshot_iter
         self.t += 1
-        # the host waits here for the aggregation's scalars
-        with trace.span("server.sync", reads=3 + (raw_norm is None)):
-            gamma_h, eta_h, dist_h = float(gamma), float(eta), float(dist)
-            dnorm_h = float(dnorm) if raw_norm is None else raw_norm
+        # the host waits here for the aggregation's (gamma, eta, dist,
+        # dnorm), read in one transfer: gamma must reach the host, since
+        # the client's next K picks a compiled program by shape
+        with trace.span("server.sync", reads=1, d2h_bytes=scalars.nbytes):
+            host = np.asarray(scalars)
+        gamma_h, eta_h, dist_h, dnorm_h = host.tolist()
+        if raw_norm is not None:
+            dnorm_h = raw_norm
         with trace.span("server.book"):
             self.gmis.append(self.t, self._gmis_state())
-            self.gmis.on_aggregate(eta, delta)
+            # the host's f32 eta: the same bits the AXPY applied
+            self.gmis.on_aggregate(host[1], delta)
             k_next = self.kctl.observe(upd.client_id, gamma_h)
             # history semantics under screening: eta is the effective
             # multiplier on the RAW arriving delta (eta * clip scale),

@@ -38,15 +38,16 @@ def _aggregate_step(x_t, x_stale, delta, scales, drift, *, lam, eps, cap,
                    interpret, in_place=False, reduce=lambda sq: sq):
     """One Eq.(5-7) step: a norms sweep over (x_t or ``drift``, x_stale,
     delta), ``reduce`` over its partial sums (a cross-shard ``psum`` under
-    a mesh), scalar gamma/eta, an AXPY sweep. Returns (new_vec, gamma,
-    eta, dist, dnorm)."""
+    a mesh), scalar gamma/eta, an AXPY sweep. Returns (new_vec, scalars):
+    ``scalars`` is f32 (gamma, eta, dist, dnorm) in one (4,) array, so the
+    host reads them in one transfer."""
     sq = reduce(fedagg.fedagg_norms(x_t if drift is None else drift,
                                     x_stale, delta, scales,
                                     interpret=interpret))
     gamma, eta, dist, dnorm = gamma_eta_from_sq(sq[0], sq[1], lam, eps, cap)
     new = fedagg.fedagg_axpy(x_t, delta, eta, scales, interpret=interpret,
                              in_place=in_place)
-    return new, gamma, eta, dist, dnorm
+    return new, jnp.stack([gamma, eta, dist, dnorm])
 
 
 # The entry points are jit-cached: the server calls them once per arrival
@@ -77,8 +78,9 @@ def flat_aggregate_program(in_place: bool):
 def flat_aggregate(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array, *,
                    scales=None, drift=None, lam: float, eps: float,
                    cap: float = 0.0, in_place: bool = False):
-    """One Eq.(5-7) step on padded flat vectors. Returns (new_vec, gamma,
-    eta, dist, dnorm); dnorm is the norm of the delta the AXPY applies.
+    """One Eq.(5-7) step on padded flat vectors. Returns (new_vec,
+    scalars), ``scalars`` the f32 (4,) array (gamma, eta, dist, dnorm);
+    dnorm is the norm of the delta the AXPY applies.
 
     ``delta`` is f32 or bf16, or int8 with its block ``scales``. Eq. 6's
     distance is ||x_t - x_stale||; displacement staleness (DESIGN.md §3)
@@ -92,21 +94,39 @@ def flat_aggregate(x_t: jax.Array, x_stale: jax.Array, delta: jax.Array, *,
         interpret=None)
 
 
-_norms_batched = jax.jit(fedagg.fedagg_norms_batched,
-                         static_argnames=("interpret",))
+def pack_gram(d0, dn_sq, cross, gram):
+    """The Gram sweep's outputs d0 (B,), dn_sq (B,), cross (B, B) and
+    gram (B, B) as one f32 vector of length 2B + 2B², in that order, so
+    the host reads them in one transfer."""
+    return jnp.concatenate([d0, dn_sq, cross.ravel(), gram.ravel()])
+
+
+def unpack_gram(packed: np.ndarray, b: int):
+    """:func:`pack_gram`'s four arrays as views of its host copy."""
+    bb = b * b
+    return (packed[:b], packed[b:2 * b],
+            packed[2 * b:2 * b + bb].reshape(b, b),
+            packed[2 * b + bb:].reshape(b, b))
+
+
+def _gram_sweep(x_t, x_stales, deltas, scales=None, *, interpret=None):
+    return pack_gram(*fedagg.fedagg_norms_batched(
+        x_t, x_stales, deltas, scales, interpret=interpret))
+
+
+_norms_batched = jax.jit(_gram_sweep, static_argnames=("interpret",))
 
 _apply_batched = jax.jit(fedagg.fedagg_apply_batched,
                          static_argnames=("interpret",))
 
 
-def _host_schedule(d0, dn_sq, cross, gram, *, lam: float, eps: float,
-                   cap: float, screen):
-    """Read the Gram sweep's outputs to the host and resolve the
-    sequential-equivalence schedule there (screening folded in). Returns
-    (etas, gammas, dists, dnorms, keep)."""
-    with trace.span("server.sync", reads=4):
-        d0, dn_sq, cross, gram = (np.asarray(a)
-                                  for a in (d0, dn_sq, cross, gram))
+def _host_schedule(packed, b: int, *, lam: float, eps: float, cap: float,
+                   screen):
+    """Read the packed Gram sweep (:func:`pack_gram`) to the host in one
+    transfer and resolve the sequential-equivalence schedule there
+    (screening folded in). Returns (etas, gammas, dists, dnorms, keep)."""
+    with trace.span("server.sync", reads=1, d2h_bytes=packed.nbytes):
+        d0, dn_sq, cross, gram = unpack_gram(np.asarray(packed), b)
     keep = None
     if screen is not None:
         dns = np.sqrt(np.maximum(np.asarray(dn_sq, np.float64), 0.0))
@@ -120,11 +140,12 @@ def _host_schedule(d0, dn_sq, cross, gram, *, lam: float, eps: float,
 
 def _batched_drain(norms, apply, x_t, x_stales, deltas, scales, *, lam, eps,
                   cap, screen):
-    """The Gram sweep ``norms``, the host schedule, the apply sweep
-    ``apply``; both sweeps take the int8 ``scales`` last where given."""
+    """The Gram sweep ``norms`` (packed by :func:`pack_gram`), the host
+    schedule, the apply sweep ``apply``; both sweeps take the int8
+    ``scales`` last where given."""
     payload = () if scales is None else (scales,)
     etas, gammas, dists, dnorms, keep = _host_schedule(
-        *norms(x_t, x_stales, deltas, *payload),
+        norms(x_t, x_stales, deltas, *payload), deltas.shape[0],
         lam=lam, eps=eps, cap=cap, screen=screen)
     new = apply(x_t, deltas, jnp.asarray(etas), *payload)
     return new, etas, gammas, dists, dnorms, keep

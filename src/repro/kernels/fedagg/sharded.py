@@ -80,15 +80,14 @@ def _aggregate(shards, lam, eps, cap, interpret, quant, displaced):
 
     in_specs = ((FLAT_VEC_SPEC,) * 3 + (FLAT_SCALES_SPEC,) * quant
                 + (FLAT_VEC_SPEC,) * displaced)
-    return _smap(body, shards, in_specs,
-                 (FLAT_VEC_SPEC, _REP, _REP, _REP, _REP))
+    return _smap(body, shards, in_specs, (FLAT_VEC_SPEC, _REP))
 
 
 def flat_aggregate(x_t, x_stale, delta, *, scales=None, drift=None, lam,
                    eps, cap=0.0, shards, in_place=False):
     """Sharded ``ops.flat_aggregate``: one Eq.(5-7) dispatch, one
-    cross-shard psum. Returns (new_vec [model-sharded], gamma, eta, dist,
-    dnorm). It always writes a fresh vector: ``in_place`` must be False."""
+    cross-shard psum. Returns (new_vec [model-sharded], scalars). It
+    always writes a fresh vector: ``in_place`` must be False."""
     if in_place:
         raise ValueError("the sharded aggregation does not write in place")
     fn = _aggregate(int(shards), float(lam), float(eps), float(cap),
@@ -100,18 +99,18 @@ def flat_aggregate(x_t, x_stale, delta, *, scales=None, drift=None, lam,
 
 # Two dispatches with the host-side sequential-equivalence schedule
 # between them, exactly like ops.flat_aggregate_batched: the Gram sweep
-# psums all four norm outputs (the only collective), the apply sweep is
-# shard-local.
+# psums all four norm outputs (the only collective) and packs them for
+# the host's one read, the apply sweep is shard-local.
 
 @functools.lru_cache(maxsize=None)
 def _norms_batched(shards, interpret, quant):
     def body(*args):
-        return _psum(fedagg.fedagg_norms_batched(*args, interpret=interpret))
+        return ops.pack_gram(*_psum(
+            fedagg.fedagg_norms_batched(*args, interpret=interpret)))
 
     return _smap(body, shards,
                  (FLAT_VEC_SPEC, FLAT_STACKED_SPEC, FLAT_STACKED_SPEC)
-                 + (FLAT_STACKED_SCALES_SPEC,) * quant,
-                 (_REP, _REP, _REP, _REP))
+                 + (FLAT_STACKED_SCALES_SPEC,) * quant, _REP)
 
 
 @functools.lru_cache(maxsize=None)

@@ -74,6 +74,6 @@ def flat_aggregate_tree(x_t, x_stale, delta, **kw):
     from repro.kernels.fedagg import ops
     from repro.utils import pytree as pt
     spec = pt.FlatSpec(x_t, block=ops._BLOCK)
-    new, *scalars = ops.flat_aggregate(
+    new, scalars = ops.flat_aggregate(
         spec.flatten(x_t), spec.flatten(x_stale), spec.flatten(delta), **kw)
     return AggregationResult(spec.unflatten(new), *scalars)

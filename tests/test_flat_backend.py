@@ -1,11 +1,14 @@
 """Flat-state server runtime: FlatSpec/FlatParams adapter, pytree vs pallas
 backend parity, and the batched burst path."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import FedConfig
+from repro.core.compression import quantize_vec
 from repro.core.server import AsyncFedEDServer, ClientUpdate, make_server
 from repro.utils import pytree as pt
 
@@ -21,6 +24,33 @@ def mk_delta(seed, like, scale=0.05):
     ks = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
     new = [scale * jax.random.normal(k, l.shape) for k, l in zip(ks, leaves)]
     return jax.tree.unflatten(jax.tree.structure(like), new)
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+def _fold_device_eta(srv):
+    """Make ``srv`` fold the aggregation program's device eta into its
+    displacement accumulators, as the reference for the host's f32."""
+    seen = []
+    if srv.backend == "pallas":
+        agg = srv._agg["flat_aggregate"]
+
+        def spy(*a, **kw):
+            out = agg(*a, **kw)
+            seen.append(out[1])
+            return out
+        srv._agg["flat_aggregate"] = spy
+    else:
+        agg = srv._aggregate_pytree
+
+        def spy(upd):
+            seen.append(agg(upd))
+            return seen[-1]
+        srv._aggregate_pytree = spy
+    fold = srv.gmis.on_aggregate
+    srv.gmis.on_aggregate = lambda eta, delta: fold(seen[-1][1], delta)
 
 
 class TestFlatSpec:
@@ -71,6 +101,46 @@ class TestBackendParity:
                                    rtol=1e-4, atol=1e-6)
         assert ([r.k_next for r in s1.history]
                 == [r.k_next for r in s2.history])
+
+    @pytest.mark.parametrize("backend,compression", [
+        ("pytree", "off"), ("pallas", "off"), ("pallas", "int8")])
+    def test_displacement_folds_the_aggregations_own_eta(self, backend,
+                                                        compression):
+        """The server reads (gamma, eta, dist, dnorm) to the host in one
+        transfer and folds the host's f32 eta into every displacement
+        accumulator: bitwise what folding the aggregation program's own
+        device eta gives."""
+        fed = FedConfig(lam=1.0, eps=1.0, staleness_cap=4.0,
+                        delta_compression=compression)
+        runs = []
+        for device_eta in (False, True):
+            srv = make_server("asyncfeded", mk_params(), fed,
+                              gmis_mode="displacement", backend=backend)
+            if device_eta:
+                _fold_device_eta(srv)
+            replies = [srv.on_connect(i) for i in range(3)]
+            for step in range(6):
+                cid = step % 3
+                delta = mk_delta(step, mk_params())
+                if compression != "off":
+                    spec = srv._flat.spec
+                    delta = quantize_vec(spec.flatten(delta), compression,
+                                         spec.n)
+                srv.on_update(ClientUpdate(cid, replies[cid].iteration, 5,
+                                           delta))
+                replies[cid] = srv.on_connect(cid)
+            runs.append(srv)
+        host, device = runs
+        assert ([dataclasses.astuple(h) for h in host.history]
+                == [dataclasses.astuple(h) for h in device.history])
+        for l1, l2 in zip(jax.tree.leaves(host.params),
+                          jax.tree.leaves(device.params)):
+            np.testing.assert_array_equal(_bits(l1), _bits(l2))
+        assert host.gmis._disp.keys() == device.gmis._disp.keys()
+        for cid in host.gmis._disp:
+            for l1, l2 in zip(jax.tree.leaves(host.gmis._disp[cid]),
+                              jax.tree.leaves(device.gmis._disp[cid])):
+                np.testing.assert_array_equal(_bits(l1), _bits(l2))
 
     def test_reply_params_structure_preserved(self):
         fed = FedConfig()

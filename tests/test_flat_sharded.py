@@ -182,8 +182,19 @@ class TestShardedServerEquivalence:
         assert batched
         for d in batched:
             kernels, = within("server.kernels", d)
-            assert [s[3]["reads"] for s in within("server.sync", d)] == [4]
+            b = d[3]["B"]
+            # d0 and dn_sq (B,), cross and gram (B, B), f32, in one read
+            assert [s[3] for s in within("server.sync", d)] == [
+                {"reads": 1, "d2h_bytes": 4 * (2 * b + 2 * b * b)}]
             assert len(within("server.sync", kernels)) == 1
+        # a burst of one drains sequentially: (gamma, eta, dist, dnorm)
+        # psum'd across the shards, f32, in one read
+        seq = [s for s in spans if s[0] == "server.drain"
+               and s[3]["path"] == "seq"]
+        assert seq
+        for d in seq:
+            assert [s[3] for s in within("server.sync", d)] == [
+                {"reads": 1, "d2h_bytes": 16}]
 
     def test_int8_burst(self, multidevice):
         """int8 payloads with their block scales through the sharded

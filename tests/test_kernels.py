@@ -6,6 +6,8 @@ import numpy as np
 import pytest
 
 from conftest import flat_aggregate_tree
+from repro.core import compression
+from repro.core.aggregation import gamma_eta_from_sq
 from repro.kernels.fedagg import fedagg
 from repro.kernels.fedagg import ref as fedagg_ref
 from repro.kernels.fedagg.ops import flat_aggregate_batched, pad_flat_vector
@@ -96,6 +98,53 @@ class TestFedAgg:
             r2.params["w"].astype(jnp.float32), rtol=tol, atol=1e-5)
 
 
+def _payload(d, payload):
+    """A flat f32 delta as the kernels take it: f32, a bf16 wire payload,
+    or int8 with its block scales."""
+    if payload == "f32":
+        return d, None
+    cd = compression.quantize_vec(d, payload, int(d.shape[0]))
+    return cd.q, cd.scales
+
+
+@jax.jit
+def _norms_then_scalars(x, x_stale, delta, scales):
+    """Eq. 6-7's scalars from the norms sweep's own squared sums, in a
+    program of their own."""
+    sq = fedagg.fedagg_norms(x, x_stale, delta, scales)
+    return gamma_eta_from_sq(sq[0], sq[1], 1.5, 0.5, 4.0)
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("payload,in_place", [
+    ("f32", True), ("f32", False), ("bf16", False), ("int8", False)])
+@pytest.mark.parametrize("mode", ["ring", "displacement"])
+def test_flat_aggregate_scalars_are_the_norms_sweeps(payload, in_place,
+                                                     mode):
+    """``flat_aggregate`` hands back (gamma, eta, dist, dnorm) as one f32
+    (4,) array, bitwise the four scalars of the norms sweep's sums."""
+    from repro.kernels.fedagg import ops
+    n = 2 * BLOCK
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    xt, other, d = (jax.random.normal(k, (n,), jnp.float32) for k in keys)
+    delta, scales = _payload(0.05 * d, payload)
+    # ring: the stale model; displacement: the drift against zeros
+    stale, drift = ((xt + 0.1 * other, None) if mode == "ring"
+                    else (jnp.zeros_like(xt), 0.1 * other))
+    want = _norms_then_scalars(xt if drift is None else drift, stale,
+                               delta, scales)
+    mine = delta + 0 if in_place else delta
+    _, scalars = ops.flat_aggregate(xt, stale, mine, scales=scales,
+                                    drift=drift, lam=1.5, eps=0.5, cap=4.0,
+                                    in_place=in_place)
+    assert scalars.shape == (4,) and scalars.dtype == jnp.float32
+    np.testing.assert_array_equal(_bits(scalars),
+                                  _bits(jnp.stack(want)))
+
+
 class TestFedAggBatched:
     def _inputs(self, b, nblocks, dtype=jnp.float32, seed=0):
         n = BLOCK * nblocks
@@ -126,6 +175,26 @@ class TestFedAggBatched:
         np.testing.assert_allclose(got.astype(jnp.float32),
                                    want.astype(jnp.float32),
                                    rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("b", [2, 15])
+    @pytest.mark.parametrize("payload", ["f32", "int8"])
+    def test_packed_gram_splits_into_the_kernels_outputs(self, b, payload):
+        """The drain's one read of the Gram sweep splits back into exactly
+        the four arrays the kernel emits."""
+        from repro.kernels.fedagg import ops
+        xt, xs, d = self._inputs(b, 1, seed=b)
+        rows = [_payload(r, payload) for r in d]
+        deltas = jnp.stack([q for q, _ in rows])
+        scales = (None if payload == "f32"
+                  else jnp.stack([s for _, s in rows]))
+        want = jax.jit(fedagg.fedagg_norms_batched)(xt, xs, deltas, scales)
+        packed = ops._norms_batched(
+            xt, xs, deltas, *(() if scales is None else (scales,)))
+        assert packed.shape == (2 * b + 2 * b * b,)
+        got = ops.unpack_gram(np.asarray(packed), b)
+        for g_, w_ in zip(got, want, strict=True):
+            assert g_.shape == w_.shape
+            np.testing.assert_array_equal(_bits(g_), _bits(w_))
 
     @pytest.mark.parametrize("b", [2, 5])
     def test_sequential_equivalence(self, b):
@@ -222,13 +291,11 @@ class TestFedAggSharded:
         return jnp.asarray(xt), jnp.asarray(xs), jnp.asarray(d)
 
     def _assert_single(self, got, want):
-        gv, *gs = got
-        wv, *ws = want
+        (gv, gs), (wv, ws) = got, want
         np.testing.assert_allclose(np.asarray(jax.device_get(gv)),
                                    np.asarray(jax.device_get(wv)),
                                    rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose([float(x) for x in gs],
-                                   [float(x) for x in ws],
+        np.testing.assert_allclose(np.asarray(gs), np.asarray(ws),
                                    rtol=1e-4, atol=1e-6)
 
     def test_shards1_mesh_is_identity_layout(self):
@@ -298,6 +365,61 @@ class TestFedAggSharded:
         want = ops.flat_aggregate(xt, z, cd.q, scales=cd.scales, drift=disp,
                                   lam=1.0, eps=1.0)
         self._assert_single(got, want)
+
+    @pytest.mark.parametrize("payload", ["f32", "bf16", "int8"])
+    @pytest.mark.parametrize("mode", ["ring", "displacement"])
+    def test_scalars_are_the_norms_sweeps(self, multidevice, payload,
+                                          mode):
+        """The sharded step's (4,) scalars are bitwise Eq. 6-7 of its
+        own psum'd norms sweep."""
+        from repro.kernels.fedagg import sharded
+        from repro.sharding.specs import FLAT_SCALES_SPEC, FLAT_VEC_SPEC
+        shards = 2
+        xt, xs, d = self._padded(2 * BLOCK + 311, shards, seed=41)
+        delta, scales = _payload(d, payload)
+        stale, drift = ((xs, None) if mode == "ring"
+                        else (jnp.zeros_like(xt), xs - xt))
+        interp = fedagg.resolve_interpret()
+        quant = scales is not None
+        rest = tuple(a for a in (scales, drift) if a is not None)
+
+        def body(x, x_stale, dl, *r):
+            sq = sharded._psum(fedagg.fedagg_norms(
+                x if drift is None else r[-1], x_stale, dl,
+                r[0] if quant else None, interpret=interp))
+            return jnp.stack(gamma_eta_from_sq(sq[0], sq[1], 2.0, 1.0))
+
+        want = sharded._smap(
+            body, shards, (FLAT_VEC_SPEC,) * 3
+            + (FLAT_SCALES_SPEC,) * quant + (FLAT_VEC_SPEC,) * len(
+                rest[quant:]), sharded._REP)(xt, stale, delta, *rest)
+        _, scalars = sharded.flat_aggregate(
+            xt, stale, delta, scales=scales, drift=drift, lam=2.0, eps=1.0,
+            shards=shards)
+        assert scalars.shape == (4,)
+        np.testing.assert_array_equal(_bits(scalars), _bits(want))
+
+    def test_packed_gram_is_the_psumd_sweep(self, multidevice):
+        """The sharded Gram sweep packs its psum'd outputs for one read."""
+        from repro.kernels.fedagg import ops, sharded
+        from repro.sharding.specs import FLAT_STACKED_SPEC, FLAT_VEC_SPEC
+        b, shards = 3, 2
+        xt, _, _ = self._padded(2 * BLOCK + 71, shards, seed=43)
+        n = xt.shape[0]
+        xs = xt[None] + 0.01 * jax.random.normal(
+            jax.random.PRNGKey(44), (b, n), jnp.float32)
+        d = jax.random.normal(jax.random.PRNGKey(45), (b, n),
+                              jnp.float32) * 0.02
+        interp = fedagg.resolve_interpret()
+        want = sharded._smap(
+            lambda *a: sharded._psum(fedagg.fedagg_norms_batched(
+                *a, interpret=interp)), shards,
+            (FLAT_VEC_SPEC, FLAT_STACKED_SPEC, FLAT_STACKED_SPEC),
+            (sharded._REP,) * 4)(xt, xs, d)
+        packed = sharded._norms_batched(shards, interp, False)(xt, xs, d)
+        got = ops.unpack_gram(np.asarray(packed), b)
+        for g_, w_ in zip(got, want, strict=True):
+            np.testing.assert_array_equal(_bits(g_), _bits(w_))
 
     def test_batched_nondividing(self, multidevice):
         """Batched Gram sweep at a non-dividing remainder: one psum of

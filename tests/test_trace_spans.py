@@ -46,9 +46,10 @@ def _spans(trace_dir):
     return out
 
 
-def _traced_run(tmp_path_factory, engine, window, compression="off"):
+def _traced_run(tmp_path_factory, engine, window, compression="off",
+                algorithm="asyncfeded"):
     sim = FederatedSimulation(configs.SYNTHETIC_1_1,
-                              _fed(engine, compression), "asyncfeded",
+                              _fed(engine, compression), algorithm,
                               seed=2, batch_window=window)
     d = str(tmp_path_factory.mktemp(f"trace-{engine}"))
     with jax.profiler.trace(d):
@@ -76,6 +77,17 @@ def cohort_int8_run(tmp_path_factory):
     return _traced_run(tmp_path_factory, "cohort", 0.5, "int8")
 
 
+@pytest.fixture(scope="module")
+def loop_bf16_run(tmp_path_factory):
+    return _traced_run(tmp_path_factory, "loop", 0.0, "bf16")
+
+
+@pytest.fixture(scope="module")
+def loop_displacement_run(tmp_path_factory):
+    return _traced_run(tmp_path_factory, "loop", 0.0,
+                       algorithm="asyncfeded-displacement")
+
+
 def _named(spans, name):
     return [s for s in spans if s[0] == name]
 
@@ -101,12 +113,22 @@ def test_server_spans_nest_inside_the_drain_inside_the_loop(run, request):
             assert len(_within(c, drains)) == 1, c
 
 
-def test_sequential_drains_read_four_scalars_per_update(loop_run):
-    _, res, spans = loop_run
+def _gram_bytes(b):
+    """Bytes of a batched drain's packed Gram sweep: d0 and dn_sq (B,),
+    cross and gram (B, B), f32."""
+    return 4 * (2 * b + 2 * b * b)
+
+
+@pytest.mark.parametrize("run", ["loop_run", "loop_int8_run",
+                                 "loop_bf16_run", "loop_displacement_run"])
+def test_sequential_drains_read_their_scalars_once_per_update(run, request):
+    _, res, spans = request.getfixturevalue(run)
     drains = _named(spans, "server.drain")
     assert {d[3]["path"] for d in drains} == {"seq"}
     syncs = _named(spans, "server.sync")
-    assert [s[3]["reads"] for s in syncs] == [4] * res.total_updates
+    # gamma, eta, dist and dnorm, f32, in one read
+    assert [s[3] for s in syncs] == [{"reads": 1, "d2h_bytes": 16}] * (
+        res.total_updates)
     for ev in _named(spans, "loop.eval"):
         assert ev[3] == {"reads": 2}
 
@@ -118,7 +140,8 @@ def test_batched_drains_read_the_gram_sweep_once(cohort_run):
     assert batched and all(d[3]["B"] > 1 for d in batched)
     for d in batched:
         syncs = [s for s in _named(spans, "server.sync") if _within(s, [d])]
-        assert [s[3]["reads"] for s in syncs] == [4]
+        assert [s[3] for s in syncs] == [
+            {"reads": 1, "d2h_bytes": _gram_bytes(d[3]["B"])}]
         kernels = [s for s in _named(spans, "server.kernels")
                    if _within(s, [d])]
         sched = [s for s in _named(spans, "server.schedule")
@@ -146,7 +169,8 @@ def test_every_aggregation_stages_and_sweeps_inside_spans(run, request):
         if d[3]["path"] == "batched":
             syncs = [s for s in _named(spans, "server.sync")
                      if _within(s, [d])]
-            assert [s[3]["reads"] for s in syncs] == [4]
+            assert [s[3] for s in syncs] == [
+                {"reads": 1, "d2h_bytes": _gram_bytes(d[3]["B"])}]
             assert _within(syncs[0], _named(spans, "server.kernels"))
 
 
